@@ -67,7 +67,7 @@ __all__ = [
     "vector_streams", "dtype_casts", "host_callbacks", "donation_audit",
     "audit_solver", "audit_dist_cg", "audit_make_solver", "audit_serve",
     "audit_setup", "check_setup", "audit_structure", "check_structure",
-    "audit_entry_points", "audit_gather", "check_gather",
+    "audit_entry_points",
     "run_audit", "format_report",
 ]
 
@@ -75,7 +75,7 @@ __all__ = [
 # eqn classification
 # ---------------------------------------------------------------------------
 
-#: pjit callee names -> role. Operator kernels and the preconditioner
+#: nested-jit callee names -> role. Operator kernels and the preconditioner
 #: are charged by the ledger's mv_cost/cycle models, not as vector
 #: streams; select merges are free at the streaming floor.
 PJIT_ROLES = {
@@ -83,11 +83,6 @@ PJIT_ROLES = {
     "dia_spmv": "spmv", "dia_spmv_dots": "spmv", "_dia_fused": "spmv",
     "dia_residual_dot": "spmv", "dia_residual_df": "spmv",
     "dense_window_spmv": "spmv", "dense_window_fused": "spmv",
-    "windowed_ell_spmv": "spmv", "windowed_ell_fused": "spmv",
-    "windowed_ell_spmv_dots": "spmv",
-    "windowed_ell_block_spmv": "spmv", "windowed_ell_block_fused": "spmv",
-    "windowed_ell_block_spmv_dots": "spmv",
-    "gather_spmv": "spmv", "gather_spmv_xla": "spmv",
     "audit_precond": "precond", "apply": "precond",
     "_where": "select",
 }
@@ -131,7 +126,7 @@ def _subjaxprs(eqn) -> Iterable[Tuple[str, Any]]:
 
 def iter_eqns(jaxpr, path: str = "") -> Iterable[Tuple[Any, str]]:
     """Yield (eqn, path) over ``jaxpr`` and every sub-jaxpr (while/scan/
-    cond/pjit/shard_map/custom_* bodies; Pallas kernels excluded)."""
+    cond/jit/shard_map/custom_* bodies; Pallas kernels excluded)."""
     for eqn in jaxpr.eqns:
         yield eqn, path
         for key, sub in _subjaxprs(eqn):
@@ -213,10 +208,10 @@ class _Node:
 def _flatten(jaxpr, n: int,
              roles: Optional[Dict[str, str]] = None
              ) -> Tuple[List[_Node], set]:
-    """Flatten ``jaxpr`` into stream-model nodes. Unrecognized pjit
-    calls are inlined (their eqns join the flat graph with value
+    """Flatten ``jaxpr`` into stream-model nodes. Unrecognized nested
+    ``jit`` calls are inlined (their eqns join the flat graph with value
     identity preserved across the call boundary); recognized kernel
-    pjits stay opaque with their declared role."""
+    jits stay opaque with their declared role."""
     roles = dict(PJIT_ROLES, **(roles or {}))
     nodes: List[_Node] = []
     counter = [0]
@@ -237,7 +232,7 @@ def _flatten(jaxpr, n: int,
 
         for eqn in jx.eqns:
             prim = eqn.primitive.name
-            if prim == "pjit":
+            if prim == "jit":
                 pname = str(eqn.params.get("name", ""))
                 role = roles.get(pname)
                 if role is None:
@@ -493,7 +488,7 @@ def _probe_problem(m: int = 8):
 
 def _audit_precond(dinv):
     """A named, jitted Jacobi preconditioner: shows up in the traced
-    body as one opaque ``audit_precond`` pjit (role 'precond'), exactly
+    body as one opaque ``audit_precond`` jit (role 'precond'), exactly
     like the real hierarchy apply is priced — by the cycle model, not as
     Krylov vector streams."""
     import jax
@@ -976,93 +971,6 @@ def check_structure(rec: Dict[str, Any]) -> List[Dict[str, Any]]:
     return out
 
 
-def audit_gather() -> List[Dict[str, Any]]:
-    """Abstractly trace the gather-SpMV pair (ops/pallas_gather.py) —
-    the per-slot unrolled kernel (interpret build, so the trace works
-    on any backend; the Pallas body itself is _NO_DESCEND territory)
-    and its take-along XLA fallback — and record the same census
-    :func:`audit_setup` keeps: host callbacks, collectives, float-width
-    casts on matrix-sized values. Checked by :func:`check_gather`
-    against ``ledger.GATHER_CONTRACTS``. ``jax.make_jaxpr`` only, no
-    execution."""
-    import jax
-    import jax.numpy as jnp
-    from amgcl_tpu.ops import pallas_gather as pg
-
-    n_tiles, tile, K = 2, 1024, 4
-    win = 2048
-    n = n_tiles * tile
-    starts = jnp.zeros(n_tiles, jnp.int32)
-    cols = jnp.zeros((n_tiles, tile, K), jnp.int32)
-    vals = jnp.ones((n_tiles, tile, K), jnp.float32)
-    x = jnp.ones(n, jnp.float32)
-    recs: List[Dict[str, Any]] = []
-    for entry, fn in (
-            ("ops.gather_spmv",
-             lambda s, c, v, xv: pg.gather_spmv(
-                 s, c, v, xv, win=win, n_out=n, interpret=True)),
-            ("ops.gather_spmv_xla",
-             lambda s, c, v, xv: pg.gather_spmv_xla(
-                 s, c, v, xv, n_out=n))):
-        try:
-            jx = jax.make_jaxpr(fn)(starts, cols, vals, x)
-            recs.append({
-                "entry": entry, "n": n,
-                "collectives": collective_census(jx.jaxpr),
-                "casts": [c for c in dtype_casts(jx.jaxpr, 1)
-                          if c["elements"] >= n],
-                "host_callbacks": host_callbacks(jx.jaxpr)})
-        except Exception as e:
-            recs.append({"entry": entry,
-                         "skipped": "trace failed: %r" % (e,)})
-    return recs
-
-
-def check_gather(rec: Dict[str, Any]) -> List[Dict[str, Any]]:
-    """Findings for one audit_gather record against
-    ``ledger.GATHER_CONTRACTS`` — the gather-SpMV pair must stay a pure
-    streaming SpMV: no host callbacks, no collectives, no float-width
-    casts on matrix-sized values."""
-    from amgcl_tpu.telemetry.ledger import GATHER_CONTRACTS
-    contract = GATHER_CONTRACTS.get(rec["entry"])
-    out: List[Dict[str, Any]] = []
-    if contract is None:
-        return out
-    if rec.get("skipped"):
-        out.append({"severity": "info", "pass": "host-sync",
-                    "entry": rec["entry"], "message": rec["skipped"]})
-        return out
-    if len(rec["host_callbacks"]) != contract["host_callbacks"]:
-        out.append({
-            "severity": "error", "pass": "host-sync",
-            "entry": rec["entry"],
-            "message": "host callback %r inside the gather-SpMV "
-            "program — a device->host round trip per Krylov iteration "
-            "serializes the solve"
-            % rec["host_callbacks"][0]["primitive"]})
-    cen = rec["collectives"]
-    n_coll = sum(cen.get(k, 0) for k in ("psum", "ppermute",
-                                         "all_gather", "all_to_all"))
-    if n_coll != contract["collectives"]:
-        out.append({
-            "severity": "error", "pass": "collectives",
-            "entry": rec["entry"],
-            "message": "%d collective(s) in the single-device "
-            "gather-SpMV, contract says %d (the sharded SpMV lives in "
-            "parallel/)" % (n_coll, contract["collectives"])})
-    narrowing = [c for c in rec["casts"] if c["kind"] == "downcast"]
-    if len(narrowing) != contract["narrowing_casts"]:
-        out.append({
-            "severity": "error", "pass": "dtype",
-            "entry": rec["entry"],
-            "message": "%d narrowing float cast(s) on matrix-sized "
-            "values inside the gather-SpMV (contract: %d) — the kernel "
-            "accumulates in the value dtype; widening happens only at "
-            "the declared output seam"
-            % (len(narrowing), contract["narrowing_casts"])})
-    return out
-
-
 def check_serve(rec: Dict[str, Any]) -> List[Dict[str, Any]]:
     """Donation contract of the resident loop: the lowered program must
     alias exactly ``DONATION_CONTRACTS['serve.solve_step']`` argument
@@ -1311,9 +1219,6 @@ def run_audit(solvers: Optional[Sequence[str]] = None,
     rec = audit_structure()
     records.append(rec)
     findings += check_structure(rec)
-    for rec in audit_gather():
-        records.append(rec)
-        findings += check_gather(rec)
     findings += check_entry_points()
     errors = [f for f in findings if f["severity"] == "error"]
     return {"records": records, "findings": findings,

@@ -21,7 +21,7 @@ Two halves (ISSUE 13):
 *recover* (converged, parity with the un-faulted solve) or *fail
 cleanly* (typed error + flight bundle) under a global deadline.
 
-The typed error taxonomy below is the "fails cleanly" contract: every
+The typed error hierarchy below is the "fails cleanly" contract: every
 fault path that gives up raises one of these (all ``RuntimeError``
 subclasses, so existing broad handlers keep working).
 """
@@ -30,7 +30,7 @@ from __future__ import annotations
 
 
 class FaultError(RuntimeError):
-    """Base of the typed fault/recovery error taxonomy."""
+    """Base of the typed fault/recovery error hierarchy."""
 
 
 class DeviceLostError(FaultError):
@@ -74,7 +74,7 @@ class AdmissionError(AllocationError):
     farm budget cannot fit the operator. The message names
     AMGCL_TPU_FARM_MAX_BYTES (the existing test contract). A subclass
     of :class:`AllocationError`: the ``alloc.farm`` injection and the
-    modeled budget path share the typed taxonomy with real OOMs."""
+    modeled budget path share the typed hierarchy with real OOMs."""
 
 
 class RecoveryExhausted(FaultError):

@@ -10,7 +10,7 @@ passes when its injected fault either
 * **recovers** — the solve converges and matches the un-faulted
   baseline within tolerance (solution parity), or the serving surface
   absorbs the fault (futures resolve, worker restarts, retries land); or
-* **fails cleanly** — the typed error taxonomy (``amgcl_tpu.faults``)
+* **fails cleanly** — the typed error hierarchy (``amgcl_tpu.faults``)
   reaches the caller and a flight bundle is written when a dump dir is
   configured.
 

@@ -11,9 +11,9 @@ every caller treats this module as optional.
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
-import tempfile
 import threading
 
 import numpy as np
@@ -27,15 +27,27 @@ _CACHE_DIR = os.path.join(os.path.dirname(os.path.abspath(__file__)),
 
 
 def _build() -> str:
+    """Path of the library built from the current source: the file name
+    carries a hash of ``setup_kernels.cpp``, so a copied tree (whose
+    mtimes say nothing) rebuilds exactly when the source changed."""
+    with open(_SRC, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:16]
     os.makedirs(_CACHE_DIR, exist_ok=True)
-    so = os.path.join(_CACHE_DIR, "libamgcl_tpu_native.so")
-    if os.path.exists(so) and os.path.getmtime(so) >= os.path.getmtime(_SRC):
+    so = os.path.join(_CACHE_DIR, "libamgcl_tpu_native-%s.so" % digest)
+    if os.path.exists(so):
         return so
     tmp = so + ".tmp%d" % os.getpid()
     cmd = ["g++", "-O3", "-fopenmp", "-shared", "-fPIC", "-std=c++17",
            "-o", tmp, _SRC]
     subprocess.run(cmd, check=True, capture_output=True)
     os.replace(tmp, so)
+    for name in os.listdir(_CACHE_DIR):      # builds of older sources
+        old = os.path.join(_CACHE_DIR, name)
+        if name.endswith(".so") and old != so:
+            try:
+                os.remove(old)
+            except OSError:
+                pass
     return so
 
 

@@ -520,8 +520,8 @@ def to_device(A: CSR, fmt: str = "auto", dtype=jnp.float32,
             elif f == "dwin" and not is_cplx and not A.is_block \
                     and A.shape[0] == A.shape[1] and on_tpu:
                 # gather-free dense-window blocks (ops/densewin.py): on
-                # real TPU the windowed-ELL Pallas gather does not
-                # legalize and the XLA take path runs at gather speed
+                # real TPU windowed ELL has no kernel (the in-kernel gather
+                # does not lower) and its XLA take runs at gather speed
                 # (~1/800 of HBM bw, r5 measurement) — trading HBM
                 # capacity (n·win·itemsize, budget-gated) for streaming
                 # wins whenever the matrix has banded locality. SQUARE
@@ -542,13 +542,11 @@ def to_device(A: CSR, fmt: str = "auto", dtype=jnp.float32,
                 _mark_candidate(cands, "dwin", why)
             elif f == "well" and not is_cplx:
                 # unstructured but banded (e.g. after Cuthill-McKee or
-                # the executed reorder): windowed ELL replaces the
-                # HBM-serialized gather with per-tile VMEM windows, for
-                # scalar AND block values (the budget scales by the
-                # block column width inside csr_to_windowed_ell).
-                # Auto-selection keeps a tighter VMEM budget than the
-                # explicit 'well' format so the window + pipeline tiles
-                # cannot blow VMEM at solver-jit time
+                # the executed reorder): windowed ELL bins rows into
+                # tiles with narrow column windows, for scalar AND block
+                # values (the window bound scales by the block column
+                # width inside csr_to_windowed_ell); auto-selection keeps
+                # a tighter bound than the explicit 'well' format
                 from amgcl_tpu.ops.unstructured import \
                     csr_to_windowed_ell
                 why = {}
@@ -642,11 +640,11 @@ def spmv(A, x):
 def residual(f, A, x):
     """r = f - A x (interface.hpp `residual`).
 
-    DIA and windowed-ELL operators take a fused single-pass Pallas kernel
+    DIA and dense-window operators take a fused single-pass Pallas kernel
     on TPU — the composed spmv + subtract costs an extra HBM round-trip of
-    A x because XLA cannot fuse across the pallas_call boundary. Plain
-    ELL/Dense stay composed: their mv is pure XLA, and XLA fuses the
-    subtraction into the gather/matmul consumer already."""
+    A x because XLA cannot fuse across the pallas_call boundary. ELL,
+    windowed ELL and Dense stay composed: their mv is pure XLA, and XLA
+    fuses the subtraction into the gather/matmul consumer already."""
     with _phase("residual/" + type(A).__name__):
         return _residual(f, A, x)
 
@@ -661,16 +659,6 @@ def _residual(f, A, x):
         if ip is not None:
             from amgcl_tpu.ops.pallas_spmv import dia_residual
             return dia_residual(A.offsets, A.data, f, x, interpret=ip)
-    from amgcl_tpu.ops.unstructured import WindowedEllMatrix
-    if isinstance(A, WindowedEllMatrix):
-        ip = A._pallas_mode(x, f, kernel="fused")
-        if ip is not None:
-            from amgcl_tpu.ops.unstructured import (
-                windowed_ell_residual, windowed_ell_block_residual)
-            fn = windowed_ell_residual if A.block == (1, 1) \
-                else windowed_ell_block_residual
-            return fn(A.window_starts, A.cols_local, A.vals, f, x, A.win,
-                      A.shape[0], interpret=ip)
     from amgcl_tpu.ops.densewin import DenseWindowMatrix
     if isinstance(A, DenseWindowMatrix):
         ip = A._pallas_mode(x, f, kernel="fused")
@@ -683,10 +671,9 @@ def _residual(f, A, x):
 
 def scaled_correction(A, w, f, x):
     """x + w ∘ (f − A x) in one fused pass when the operator format has a
-    kernel for it (DIA, windowed-ELL scalar; windowed-ELL block with a
-    per-node (b, b) scale), else None — the smoother seam asks here so
-    format dispatch lives next to residual/spmv_dots instead of inside
-    every smoother."""
+    kernel for it (DIA, dense window), else None — the smoother seam asks
+    here so format dispatch lives next to residual/spmv_dots instead of
+    inside every smoother."""
     with _phase("scaled_correction/" + type(A).__name__):
         return _scaled_correction(A, w, f, x)
 
@@ -698,21 +685,6 @@ def _scaled_correction(A, w, f, x):
             from amgcl_tpu.ops.pallas_spmv import dia_scaled_correction
             return dia_scaled_correction(A.offsets, A.data, w, f, x,
                                          interpret=ip)
-    from amgcl_tpu.ops.unstructured import WindowedEllMatrix
-    if isinstance(A, WindowedEllMatrix):
-        scalar_ok = w.ndim == 1 and A.block == (1, 1)
-        block_ok = (w.ndim == 3 and A.block != (1, 1)
-                    and A.block[0] == A.block[1] == w.shape[-1])
-        if scalar_ok or block_ok:
-            ip = A._pallas_mode(x, f, w, kernel="fused")
-            if ip is not None:
-                from amgcl_tpu.ops.unstructured import (
-                    windowed_ell_scaled_correction,
-                    windowed_ell_block_scaled_correction)
-                fn = windowed_ell_scaled_correction if scalar_ok \
-                    else windowed_ell_block_scaled_correction
-                return fn(A.window_starts, A.cols_local, A.vals, w, f, x,
-                          A.win, A.shape[0], interpret=ip)
     from amgcl_tpu.ops.densewin import DenseWindowMatrix
     if isinstance(A, DenseWindowMatrix) and w.ndim == 1:
         ip = A._pallas_mode(x, f, w, kernel="fused")
@@ -798,20 +770,6 @@ def _spmv_dots(A, x, w=None, ip=inner_product):
             from amgcl_tpu.ops.pallas_spmv import dia_spmv_dots
             y, yy, yx, yw = dia_spmv_dots(A.offsets, A.data, x, w,
                                           interpret=m)
-            return (y,) + _globalize_dots(axis, yy, yx, yw)
-    from amgcl_tpu.ops.unstructured import WindowedEllMatrix
-    if isinstance(A, WindowedEllMatrix) and fused_ip \
-            and A.shape[0] == A.shape[1] and A.block[0] == A.block[1]:
-        m = A._pallas_mode(x, kernel="dots") if w is None \
-            else A._pallas_mode(x, w, kernel="dots")
-        if m is not None:
-            from amgcl_tpu.ops.unstructured import (
-                windowed_ell_spmv_dots, windowed_ell_block_spmv_dots)
-            fn = windowed_ell_spmv_dots if A.block == (1, 1) \
-                else windowed_ell_block_spmv_dots
-            y, yy, yx, yw = fn(A.window_starts, A.cols_local, A.vals, x,
-                               w, win=A.win, n_out=A.shape[0],
-                               interpret=m)
             return (y,) + _globalize_dots(axis, yy, yx, yw)
     y = A.mv(x)
     if axis is not None:

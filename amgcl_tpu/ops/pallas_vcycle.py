@@ -256,9 +256,14 @@ def fused_down_sweep(a_flat, mt_flat, sy, sx, f, u,
         # SINGLE bf16 MXU pass by default (no XLA precision pass) — the r5
         # on-chip value check caught ~3e-3 relative error from exactly
         # this; the 0/1 pair-sum operators need f32-exact accumulation
-        red = jnp.dot(sy_ref[:], t2, preferred_element_type=jnp.float32,
+        # operands go to f32 first: Mosaic refuses a bf16 lhs for an
+        # f32-accumulating HIGHEST dot (as in the up kernel)
+        red = jnp.dot(sy_ref[:].astype(jnp.float32),
+                      t2.astype(jnp.float32),
+                      preferred_element_type=jnp.float32,
                       precision=jax.lax.Precision.HIGHEST)
-        out = jnp.dot(red, sx_ref[:], preferred_element_type=jnp.float32,
+        out = jnp.dot(red, sx_ref[:].astype(jnp.float32),
+                      preferred_element_type=jnp.float32,
                       precision=jax.lax.Precision.HIGHEST)
         o_ref[0] = out.astype(dt)
 

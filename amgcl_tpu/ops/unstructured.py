@@ -1,11 +1,11 @@
-"""Unstructured-matrix device SpMV: windowed ELL with a Pallas kernel.
+"""Unstructured-matrix device SpMV: windowed ELL.
 
 This is the TPU answer to the reference's general-sparsity GPU story
 (cuSPARSE CSR SpMV, amgcl/backend/cuda.hpp:60-843; generated block kernels,
 amgcl/backend/vexcl_static_matrix.hpp:228-1031). A TPU has no hardware
 scatter/gather against HBM — XLA lowers an arbitrary ``jnp.take`` to a
 serialized gather measured at ~130M elem/s (ops/structured.py), which makes
-a 2.4M-nnz FE matrix cost ~18 ms per SpMV. The fix here restructures the
+a 2.4M-nnz FE matrix cost ~18 ms per SpMV. The format restructures the
 access pattern instead of translating CSR:
 
 1. **Host-side row binning (RCM)**: reverse Cuthill-McKee confines each row
@@ -18,32 +18,17 @@ access pattern instead of translating CSR:
    tile's window start*. The device array is (n_tiles, tile, K) — static
    shapes, padded with window-local zeros.
 
-3. **Pallas kernel**: each grid step DMAs the tile's x-window (a contiguous,
-   statically-sized slice, start scalar-prefetched from SMEM) from HBM into
-   VMEM once — double-buffered by default, so tile t+1's transfer rides
-   under tile t's compute — then gathers from VMEM with ``jnp.take``:
-   on-chip gather bandwidth instead of HBM-serialized gather. Diagonal
-   data streams through as normal pipelined blocks.
-
-The kernel family mirrors the DIA fusion tiers: plain SpMV, fused
-residual, fused scaled-correction sweep, and fused SpMV+dots, each in a
-scalar and a block-valued variant (block columns ride a bc-wide window
-DMA with per-node matvec einsum reductions). Every variant is
-probe-compiled separately per matrix shape (``kernel_supported``); if
-Mosaic cannot legalize one on some TPU generation, just that dispatch
-falls back to the XLA path (global ``jnp.take``), keeping numerics
-identical; the bench harness records which path won.
+The SpMV is one XLA gather over absolute columns. A Pallas kernel that
+DMAs each tile's window into VMEM and gathers there does not lower on a
+v5e (Mosaic lowers only a 2-D gather within one vreg tile), so there is
+none; the dense-window format (ops/densewin.py) is the gather-free kernel
+path for unstructured operators.
 """
 
 from __future__ import annotations
 
-import functools
-import os
-
 import numpy as np
-import jax
 import jax.numpy as jnp
-from amgcl_tpu.telemetry.compile_watch import watched_jit as _watched_jit
 from jax.tree_util import register_pytree_node_class
 
 from amgcl_tpu.ops.csr import CSR
@@ -58,16 +43,14 @@ class WindowedEllMatrix:
 
     cols_local[t, r, k] = column of entry k of row t*tile+r, relative to
     window_starts[t]; padding entries point at slot 0 with val 0. The
-    window width ``win`` is the static max over tiles (rounded up), so the
-    per-tile DMA has a static shape.
+    window width ``win`` is the static max over tiles (rounded up).
 
     Block values (BCSR convention, ops/csr.py): vals gains trailing
     (br, bc) dims, cols/windows index BLOCK columns, shape is in block
     units and x is logically (ncols, bc) flattened — the same windowed
-    access pattern with a bc-wide window DMA and a per-node matvec in the
-    reduction (the reference's BCSR micro-kernels,
-    amgcl/value_type/static_matrix.hpp:43-342, recast as MXU-friendly
-    batched einsums).
+    access pattern with a per-node matvec in the reduction (the
+    reference's BCSR micro-kernels, amgcl/value_type/static_matrix.hpp:
+    43-342, recast as batched einsums).
     """
 
     def __init__(self, window_starts, cols_local, vals, shape, win,
@@ -96,46 +79,7 @@ class WindowedEllMatrix:
         shape, win, block = aux
         return cls(children[0], children[1], children[2], shape, win, block)
 
-    def _pallas_mode(self, *vecs, kernel: str = "spmv"):
-        """None = XLA path; else the ``interpret`` flag for the windowed
-        kernels (False on real TPU after a support probe, True under the
-        CI interpret hook) — the same dispatch seam as DiaMatrix.
-        ``kernel`` names the variant being dispatched ('spmv' / 'fused' /
-        'dots'): each is probed separately, so a legalization failure in
-        one (e.g. the SMEM-accumulating dots) does not disable the
-        others."""
-        from amgcl_tpu.ops.pallas_spmv import pallas_mode
-        m = pallas_mode(self.dtype, *(v.dtype for v in vecs))
-        if m is False and not kernel_supported(
-                self.win, self.cols_local.shape[2], self.dtype,
-                self.block, kernel):
-            return None
-        return m
-
     def mv(self, x):
-        if self.block == (1, 1):
-            # narrow-K scalar operators (the executed-reorder regime,
-            # ISSUE 20) prefer the per-slot unrolled gather kernel;
-            # maybe_gather_spmv returns None to decline (kill switch,
-            # wide K, probe failure) and the classic chain takes over.
-            # Lazy import: pallas_gather reuses this module's DMA
-            # machinery, so importing it at the top would be circular.
-            from amgcl_tpu.ops import pallas_gather
-            y = pallas_gather.maybe_gather_spmv(self, x)
-            if y is not None:
-                return y
-        ip = self._pallas_mode(x)
-        if ip is not None:
-            if self.block == (1, 1):
-                return windowed_ell_spmv(
-                    self.window_starts, self.cols_local, self.vals, x,
-                    self.win, self.shape[0], interpret=ip)
-            return windowed_ell_block_spmv(
-                self.window_starts, self.cols_local, self.vals, x,
-                self.win, self.shape[0], interpret=ip)
-        return self._mv_xla(x)
-
-    def _mv_xla(self, x):
         # global gather: reconstruct absolute columns; one take over x
         n_tiles, tile, K = self.cols_local.shape
         cols = self.cols_local + self.window_starts[:, None, None]
@@ -160,559 +104,6 @@ class WindowedEllMatrix:
         return (self.cols_local.size * self.cols_local.dtype.itemsize
                 + self.vals.size * self.vals.dtype.itemsize
                 + self.window_starts.size * 4)
-
-
-_KERNEL_OK = {}
-
-
-def kernel_supported(win: int = 2 << 20, K: int = 4,
-                     dtype=jnp.float32, block=(1, 1),
-                     kernel: str = "spmv") -> bool:
-    """Probe-compile ONE windowed kernel variant on the current backend
-    for THIS matrix's VMEM footprint (window size, tile width K, value
-    dtype, block dims): the in-kernel gather needs Mosaic support that
-    may vary by TPU generation, and VMEM-pressure failures depend on the
-    window scratch plus the (tile, K) cols/vals blocks. Dispatch cannot
-    use try/except — inside an outer jit a legalization failure only
-    surfaces at the OUTER compile — so the path choice is made here,
-    eagerly. ``kernel`` in {'spmv', 'fused', 'dots'}: each variant is
-    probed and cached separately (per (win, K, dtype, block, kernel)),
-    because the fused/dots variants add vector streams and an SMEM
-    accumulator that can fail where the plain SpMV compiles — and a dots
-    failure must not disable the others."""
-    br, bc = int(block[0]), int(block[1])
-    # the DB flag changes the kernel geometry (scratch slots), so the
-    # probe verdict must be keyed on it — an in-process flip would
-    # otherwise reuse the other geometry's verdict
-    key = (int(win), int(K), jnp.dtype(dtype).name, br, bc, kernel,
-           _double_buffered())
-    if key not in _KERNEL_OK:
-        try:
-            starts = jnp.zeros(1, jnp.int32)
-            cols = jnp.zeros((1, _TILE, int(K)), jnp.int32)
-            scalar = (br, bc) == (1, 1)
-            vals = jnp.zeros((1, _TILE, int(K)), dtype) if scalar \
-                else jnp.zeros((1, _TILE, int(K), br, bc), dtype)
-            x = jnp.zeros(int(win) * bc, jnp.float32)
-            xs = jnp.zeros(_TILE * br, jnp.float32)   # row-shaped vector
-            if kernel == "spmv":
-                fn = windowed_ell_spmv if scalar else \
-                    windowed_ell_block_spmv
-                jax.jit(functools.partial(fn, win=int(win), n_out=_TILE)
-                        ).lower(starts, cols, vals, x).compile()
-            elif kernel == "fused":
-                # the correction mode is the superset (one more stream
-                # than residual): probing it covers both fused forms
-                if scalar:
-                    jax.jit(functools.partial(
-                        windowed_ell_fused, mode="correction",
-                        win=int(win), n_out=_TILE)
-                    ).lower(starts, cols, vals, xs, xs, xs).compile()
-                elif br == bc:
-                    S = jnp.zeros((_TILE, br, br), jnp.float32)
-                    jax.jit(functools.partial(
-                        windowed_ell_block_fused, mode="correction",
-                        win=int(win), n_out=_TILE)
-                    ).lower(starts, cols, vals, xs, x[:_TILE * bc],
-                            S).compile()
-                else:
-                    # rectangular blocks only ever dispatch the residual
-                    # form (the correction gate requires br == bc)
-                    jax.jit(functools.partial(
-                        windowed_ell_block_fused, mode="residual",
-                        win=int(win), n_out=_TILE)
-                    ).lower(starts, cols, vals, xs, x[:_TILE * bc],
-                            None).compile()
-            elif kernel == "dots":
-                if scalar:
-                    jax.jit(functools.partial(
-                        windowed_ell_spmv_dots, win=int(win),
-                        n_out=_TILE)
-                    ).lower(starts, cols, vals, xs, xs).compile()
-                elif br == bc:
-                    jax.jit(functools.partial(
-                        windowed_ell_block_spmv_dots, win=int(win),
-                        n_out=_TILE)
-                    ).lower(starts, cols, vals, xs, xs).compile()
-                else:
-                    raise ValueError("dots needs a square block")
-            else:
-                raise ValueError("unknown kernel %r" % kernel)
-            _KERNEL_OK[key] = True
-        except Exception as e:
-            from amgcl_tpu.ops.pallas_spmv import probe_report
-            probe_report("windowed_ell[%s]%r" % (kernel, key), e)
-            _KERNEL_OK[key] = False
-    return _KERNEL_OK[key]
-
-
-# Double-buffered window DMA (prefetch tile t+1's window while tile t
-# computes — the canonical Pallas latency-hiding pattern) is the default;
-# AMGCL_TPU_WELL_DB=0 falls back to the serial start/wait. Snapshotted at
-# IMPORT: jit traces and probe verdicts bake the geometry in, so an
-# in-process flip would silently reuse the other mode's artifacts —
-# A/B the two modes with one process per arm (CHIP_SESSION.md).
-_WELL_DB = os.environ.get("AMGCL_TPU_WELL_DB", "1") != "0"
-
-
-def _double_buffered() -> bool:
-    return _WELL_DB
-
-
-def _well_geometry(x, win, n_tiles, tile, K, n_vecs, out_specs):
-    """Shared window-DMA geometry for ALL windowed-ELL kernels: the padded
-    x (window DMA reads x[start : start+win]; padding keeps the last
-    window in range — starts are host-computed, start+win <= len(xp) by
-    construction), the scalar-prefetch grid spec with the HBM-x +
-    cols/vals block prefix plus ``n_vecs`` tile-blocked vector streams,
-    and the VMEM window + DMA semaphore scratch (two slots when double
-    buffering). Every kernel must read x through exactly this geometry —
-    any sizing/alignment fix here services all of them (the DIA path's
-    _dia_window lesson)."""
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    nbuf = 2 if _double_buffered() else 1
-    xp = jnp.pad(x, (0, win))
-    # index-map constants must be np.int32: Python 0 traces as i64 under
-    # jax_enable_x64 and Mosaic cannot legalize the i64/mixed-width
-    # func.return (the DIA kernels' round-2 lesson, confirmed on-chip r5)
-    _0 = np.int32(0)
-    vec_spec = pl.BlockSpec((1, tile), lambda t, starts: (t, _0))
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(n_tiles,),
-        in_specs=[
-            pl.BlockSpec(memory_space=pl.ANY),          # x stays in HBM
-            pl.BlockSpec((1, tile, K), lambda t, starts: (t, _0, _0)),
-            pl.BlockSpec((1, tile, K), lambda t, starts: (t, _0, _0)),
-        ] + [vec_spec] * n_vecs,
-        out_specs=out_specs if out_specs is not None else vec_spec,
-        scratch_shapes=[
-            pltpu.VMEM((nbuf, win), x.dtype),
-            pltpu.SemaphoreType.DMA((nbuf,)),
-        ],
-    )
-    return xp, vec_spec, grid_spec
-
-
-def _well_dma(pl, pltpu, starts_smem, x_hbm, xw, sem, win, n_tiles,
-              bc: int = 1):
-    """Per-tile x-window DMA (the one access of x). Double-buffered by
-    default: tile t+1's window transfer is issued before waiting on tile
-    t's, so the next DMA rides under this tile's compute. The slot
-    machinery is shared with the DIA kernels (pallas_spmv.window_dma —
-    one copy of the race-prone part). Returns the scratch slot holding
-    THIS tile's window."""
-    from amgcl_tpu.ops.pallas_spmv import window_dma
-
-    def dma(tile_idx, slot):
-        # builder floors starts to _WIN_ALIGN; multiple_of carries the
-        # alignment invariant Mosaic cannot infer from an SMEM value
-        start = pl.multiple_of(starts_smem[tile_idx] * np.int32(bc),
-                               _WIN_ALIGN * bc)
-        return pltpu.make_async_copy(
-            x_hbm.at[pl.ds(start, win * bc)], xw.at[slot], sem.at[slot])
-
-    return window_dma(pl, dma, pl.program_id(0), n_tiles, xw.shape[0])
-
-
-@functools.partial(_watched_jit, name="ops.windowed_ell_spmv",
-                   static_argnames=("win", "n_out", "interpret"))
-def windowed_ell_spmv(window_starts, cols_local, vals, x, win, n_out,
-                      interpret: bool = False):
-    """y = A x with per-tile VMEM x-windows (see module docstring)."""
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    n_tiles, tile, K = cols_local.shape
-    out_dtype = jnp.result_type(vals.dtype, x.dtype)
-    xp, _, grid_spec = _well_geometry(x, win, n_tiles, tile, K, 0, None)
-
-    def kernel(starts_smem, x_hbm, c_ref, v_ref, o_ref, xw, sem):
-        slot = _well_dma(pl, pltpu, starts_smem, x_hbm, xw, sem, win,
-                         n_tiles)
-        xg = jnp.take(xw[slot], c_ref[0], axis=0)  # (tile, K) VMEM gather
-        o_ref[0] = jnp.sum(v_ref[0] * xg.astype(v_ref.dtype),
-                           axis=1).astype(o_ref.dtype)
-
-    out = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((n_tiles, tile), out_dtype),
-        interpret=interpret,
-    )(window_starts, xp, cols_local, vals)
-    return out.reshape(n_tiles * tile)[:n_out]
-
-
-# -- fused residual / smoother-step / Krylov-dot kernels --------------------
-#
-# Mirror of the DIA fusion tiers (ops/pallas_spmv.py:142-307) for the
-# unstructured path: every kernel keeps windowed_ell_spmv's access pattern
-# (scalar-prefetched window start, one DMA of the x-window into VMEM, VMEM
-# gather, dense reduction) and only changes the accumulator init / output
-# expression — no new Mosaic ops, so wherever the plain SpMV legalizes
-# these do too. Composed from windowed_ell_spmv + XLA elementwise, each of
-# these costs an extra HBM round-trip of the SpMV output because XLA
-# cannot fuse across a pallas_call boundary. Reference precedent for
-# backend-specialized kernel generation: the reference's per-backend
-# static-matrix kernels (amgcl/backend/vexcl_static_matrix.hpp:228-1031).
-
-
-@functools.partial(_watched_jit, name="ops.windowed_ell_fused",
-                   static_argnames=("mode", "win", "n_out", "interpret"))
-def windowed_ell_fused(window_starts, cols_local, vals, f, x, w, mode,
-                       win, n_out, interpret: bool = False):
-    """mode='residual':  r  = f − A x;
-    mode='correction':   x' = x + w ∘ (f − A x)   (Jacobi/SPAI-0 sweep)."""
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    n_tiles, tile, K = cols_local.shape
-    n_pad = n_tiles * tile
-    out_dtype = jnp.result_type(vals.dtype, x.dtype, f.dtype)
-    vecs = [jnp.pad(f, (0, n_pad - f.shape[0]))]
-    if mode == "correction":
-        out_dtype = jnp.result_type(out_dtype, w.dtype)
-        # the x tile is streamed as its own block: tile rows need not lie
-        # inside the tile's column window for a general (rect/asym) pattern
-        vecs.append(jnp.pad(x, (0, n_pad - x.shape[0])))
-        vecs.append(jnp.pad(w, (0, n_pad - w.shape[0])))
-    xp, _, grid_spec = _well_geometry(x, win, n_tiles, tile, K,
-                                      len(vecs), None)
-
-    def kernel(starts_smem, x_hbm, c_ref, v_ref, f_ref, *rest):
-        (*w_refs, o_ref, xw, sem) = rest
-        slot = _well_dma(pl, pltpu, starts_smem, x_hbm, xw, sem, win,
-                         n_tiles)
-        xg = jnp.take(xw[slot], c_ref[0], axis=0)       # (tile, K)
-        ax = jnp.sum(v_ref[0] * xg.astype(v_ref.dtype), axis=1)
-        acc = f_ref[0].astype(out_dtype) - ax.astype(out_dtype)
-        if mode == "residual":
-            o_ref[0] = acc
-        else:
-            xt = w_refs[0][0].astype(out_dtype)
-            o_ref[0] = xt + w_refs[1][0].astype(out_dtype) * acc
-
-    out = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((n_tiles, tile), out_dtype),
-        interpret=interpret,
-    )(window_starts, xp, cols_local,
-      vals, *(v.reshape(n_tiles, tile) for v in vecs))
-    return out.reshape(n_pad)[:n_out]
-
-
-def windowed_ell_residual(window_starts, cols_local, vals, f, x, win,
-                          n_out, interpret: bool = False):
-    """r = f − A x in one pass (A in windowed-ELL storage)."""
-    return windowed_ell_fused(window_starts, cols_local, vals, f, x, None,
-                              "residual", win, n_out, interpret)
-
-
-def windowed_ell_scaled_correction(window_starts, cols_local, vals, w, f,
-                                   x, win, n_out, interpret: bool = False):
-    """x + w ∘ (f − A x) in one pass — a damped-Jacobi/SPAI-0 sweep."""
-    return windowed_ell_fused(window_starts, cols_local, vals, f, x, w,
-                              "correction", win, n_out, interpret)
-
-
-@functools.partial(_watched_jit, name="ops.windowed_ell_spmv_dots",
-                   static_argnames=("win", "n_out", "interpret"))
-def windowed_ell_spmv_dots(window_starts, cols_local, vals, x, w=None,
-                           win: int = 0, n_out: int = 0,
-                           interpret: bool = False):
-    """(y, <y, y>, <y, x>, <y, w>) in one pass, y = A x (w optional) —
-    the Krylov hot pairs (see dia_spmv_dots). Square real operators only
-    (the caller gates); per-tile partials accumulate into SMEM scalars
-    across the sequential grid steps."""
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    n_tiles, tile, K = cols_local.shape
-    n_pad = n_tiles * tile
-    out_dtype = jnp.result_type(vals.dtype, x.dtype)
-    acc_dtype = jnp.float32 if jnp.dtype(out_dtype).itemsize <= 4 \
-        else jnp.float64
-    has_w = w is not None
-    # x rides again as a tile-blocked stream for <y, x> (padding is zero,
-    # and padded rows have vals == 0, so partials equal the true dots)
-    vecs = [jnp.pad(x, (0, n_pad - x.shape[0]))]
-    if has_w:
-        vecs.append(jnp.pad(w, (0, n_pad - w.shape[0])))
-
-    def kernel(starts_smem, x_hbm, c_ref, v_ref, xt_ref, *rest):
-        (*w_refs, o_ref, dots_ref, xw, sem) = rest
-        slot = _well_dma(pl, pltpu, starts_smem, x_hbm, xw, sem, win,
-                         n_tiles)
-        t = pl.program_id(0)
-        xg = jnp.take(xw[slot], c_ref[0], axis=0)       # (tile, K)
-        y = jnp.sum(v_ref[0] * xg.astype(v_ref.dtype),
-                    axis=1).astype(out_dtype)
-        o_ref[0] = y
-        ya = y.astype(acc_dtype)
-        p_yy = jnp.sum(ya * ya)
-        p_yx = jnp.sum(ya * xt_ref[0].astype(acc_dtype))
-
-        @pl.when(t == 0)
-        def _init():
-            for j in range(2 + has_w):
-                dots_ref[0, j] = jnp.zeros((), acc_dtype)
-
-        dots_ref[0, 0] += p_yy
-        dots_ref[0, 1] += p_yx
-        if has_w:
-            dots_ref[0, 2] += jnp.sum(ya * w_refs[0][0].astype(acc_dtype))
-
-    from jax.experimental.pallas import tpu as _pltpu
-    xp, _, grid_spec = _well_geometry(
-        x, win, n_tiles, tile, K, len(vecs),
-        (pl.BlockSpec((1, tile), lambda t, starts: (t, np.int32(0))),
-         # explicit i32 map — the default map's i64 indices under x64
-         # fail Mosaic legalization (see _well_geometry)
-         pl.BlockSpec((1, 2 + has_w),
-                      lambda t, starts: (np.int32(0), np.int32(0)),
-                      memory_space=_pltpu.SMEM)))
-    y, dots = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=(
-            jax.ShapeDtypeStruct((n_tiles, tile), out_dtype),
-            jax.ShapeDtypeStruct((1, 2 + has_w), acc_dtype),
-        ),
-        interpret=interpret,
-    )(window_starts, xp, cols_local, vals,
-      *(v.reshape(n_tiles, tile) for v in vecs))
-    yy = dots[0, 0].astype(out_dtype)
-    yx = dots[0, 1].astype(out_dtype)
-    yw = dots[0, 2].astype(out_dtype) if has_w else None
-    return y.reshape(n_pad)[:n_out], yy, yx, yw
-
-
-# -- block-value kernels ----------------------------------------------------
-#
-# Same windowed access pattern with block (br, bc) values: the window DMA
-# moves bc-wide block rows of x (flat layout, so the slice is contiguous),
-# the VMEM gather fetches bc consecutive elements per referenced block
-# column, and the reduction is a batched per-node matvec einsum. Block
-# sizes are tiny (2-8), so the einsum stays VPU work — the win is the same
-# as the scalar path: on-chip gather bandwidth instead of the
-# HBM-serialized global take.
-
-
-def _well_block_geometry(x, win, bc, n_tiles, tile, K, br, n_vecs,
-                         out_specs, extra_specs=()):
-    """Block-value counterpart of _well_geometry: the x pad and VMEM
-    scratch scale by bc (flat block rows), vector streams by br;
-    ``extra_specs`` appends non-vector inputs (e.g. a block-scale
-    stream) after the vector streams."""
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    nbuf = 2 if _double_buffered() else 1
-    xp = jnp.pad(x, (0, win * bc))
-    # np.int32 index-map constants — see _well_geometry
-    _0 = np.int32(0)
-    vec_spec = pl.BlockSpec((1, tile * br), lambda t, starts: (t, _0))
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(n_tiles,),
-        in_specs=[
-            pl.BlockSpec(memory_space=pl.ANY),          # x stays in HBM
-            pl.BlockSpec((1, tile, K), lambda t, starts: (t, _0, _0)),
-            pl.BlockSpec((1, tile, K, br, bc),
-                         lambda t, starts: (t, _0, _0, _0, _0)),
-        ] + [vec_spec] * n_vecs + list(extra_specs),
-        out_specs=out_specs if out_specs is not None else vec_spec,
-        scratch_shapes=[
-            pltpu.VMEM((nbuf, win * bc), x.dtype),
-            pltpu.SemaphoreType.DMA((nbuf,)),
-        ],
-    )
-    return xp, vec_spec, grid_spec
-
-
-def _block_gather(c_ref, xw, tile, K, bc):
-    """(tile, K, bc) block-row gather from the flat VMEM window."""
-    import jax.lax as lax
-    idx = (c_ref[0].astype(jnp.int32) * np.int32(bc))[:, :, None] \
-        + lax.broadcasted_iota(jnp.int32, (tile, K, bc), 2)
-    return jnp.take(xw[:], idx.reshape(tile, K * bc),
-                    axis=0).reshape(tile, K, bc)
-
-
-@functools.partial(_watched_jit, name="ops.windowed_ell_block_spmv",
-                   static_argnames=("win", "n_out", "interpret"))
-def windowed_ell_block_spmv(window_starts, cols_local, vals, x, win, n_out,
-                            interpret: bool = False):
-    """y = A x for block windowed-ELL (vals (n_tiles, tile, K, br, bc);
-    x flat of length ncols*bc; returns flat length n_out*br)."""
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    n_tiles, tile, K, br, bc = vals.shape
-    out_dtype = jnp.result_type(vals.dtype, x.dtype)
-    xp, _, grid_spec = _well_block_geometry(x, win, bc, n_tiles, tile, K,
-                                            br, 0, None)
-
-    def kernel(starts_smem, x_hbm, c_ref, v_ref, o_ref, xw, sem):
-        slot = _well_dma(pl, pltpu, starts_smem, x_hbm, xw, sem, win,
-                         n_tiles, bc)
-        xg = _block_gather(c_ref, xw[slot], tile, K, bc)
-        y = jnp.einsum("tkij,tkj->ti", v_ref[0], xg.astype(v_ref.dtype),
-                       preferred_element_type=out_dtype)
-        o_ref[0] = y.reshape(tile * br).astype(o_ref.dtype)
-
-    out = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((n_tiles, tile * br), out_dtype),
-        interpret=interpret,
-    )(window_starts, xp, cols_local, vals)
-    return out.reshape(n_tiles * tile * br)[:n_out * br]
-
-
-@functools.partial(_watched_jit, name="ops.windowed_ell_block_fused",
-                   static_argnames=("mode", "win", "n_out", "interpret"))
-def windowed_ell_block_fused(window_starts, cols_local, vals, f, x, S,
-                             mode, win, n_out, interpret: bool = False):
-    """mode='residual':  r  = f − A x;
-    mode='correction':   x' = x + S ∘ (f − A x), S a per-node (br, br)
-    block scale (block damped-Jacobi / block SPAI-0 sweep)."""
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    n_tiles, tile, K, br, bc = vals.shape
-    n_pad = n_tiles * tile * br
-    out_dtype = jnp.result_type(vals.dtype, x.dtype, f.dtype)
-    vecs = [jnp.pad(f, (0, n_pad - f.shape[0]))]
-    extra_specs, extra_args = (), []
-    if mode == "correction":
-        out_dtype = jnp.result_type(out_dtype, S.dtype)
-        vecs.append(jnp.pad(x, (0, n_pad - x.shape[0])))
-        Sp = jnp.pad(S.reshape(-1, br, br),
-                     ((0, n_tiles * tile - S.shape[0]), (0, 0), (0, 0)))
-        extra_specs = (pl.BlockSpec(
-            (1, tile, br, br),
-            lambda t, starts: (t, np.int32(0), np.int32(0),
-                               np.int32(0))),)
-        extra_args = [Sp.reshape(n_tiles, tile, br, br)]
-    xp, _, grid_spec = _well_block_geometry(
-        x, win, bc, n_tiles, tile, K, br, len(vecs), None, extra_specs)
-    args = [window_starts, xp, cols_local, vals,
-            *(v.reshape(n_tiles, tile * br) for v in vecs), *extra_args]
-
-    def kernel(starts_smem, x_hbm, c_ref, v_ref, f_ref, *rest):
-        (*w_refs, o_ref, xw, sem) = rest
-        slot = _well_dma(pl, pltpu, starts_smem, x_hbm, xw, sem, win,
-                         n_tiles, bc)
-        xg = _block_gather(c_ref, xw[slot], tile, K, bc)
-        ax = jnp.einsum("tkij,tkj->ti", v_ref[0], xg.astype(v_ref.dtype),
-                        preferred_element_type=out_dtype)
-        acc = f_ref[0].reshape(tile, br).astype(out_dtype) - ax
-        if mode == "residual":
-            o_ref[0] = acc.reshape(tile * br)
-        else:
-            xt = w_refs[0][0].reshape(tile, br).astype(out_dtype)
-            corr = jnp.einsum("tij,tj->ti",
-                              w_refs[1][0].astype(out_dtype), acc,
-                              preferred_element_type=out_dtype)
-            o_ref[0] = (xt + corr).reshape(tile * br)
-
-    out = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((n_tiles, tile * br), out_dtype),
-        interpret=interpret,
-    )(*args)
-    return out.reshape(n_pad)[:n_out * br]
-
-
-@functools.partial(_watched_jit,
-                   name="ops.windowed_ell_block_spmv_dots",
-                   static_argnames=("win", "n_out", "interpret"))
-def windowed_ell_block_spmv_dots(window_starts, cols_local, vals, x,
-                                 w=None, win: int = 0, n_out: int = 0,
-                                 interpret: bool = False):
-    """(y, <y, y>, <y, x>, <y, w>) in one pass, y = A x for block
-    windowed-ELL — the Krylov hot pairs on the block path (see
-    dia_spmv_dots). Square (br == bc) real operators only (the caller
-    gates); per-tile partials accumulate into SMEM scalars."""
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    n_tiles, tile, K, br, bc = vals.shape
-    n_pad = n_tiles * tile * br
-    out_dtype = jnp.result_type(vals.dtype, x.dtype)
-    acc_dtype = jnp.float32 if jnp.dtype(out_dtype).itemsize <= 4 \
-        else jnp.float64
-    has_w = w is not None
-    vecs = [jnp.pad(x, (0, n_pad - x.shape[0]))]
-    if has_w:
-        vecs.append(jnp.pad(w, (0, n_pad - w.shape[0])))
-
-    def kernel(starts_smem, x_hbm, c_ref, v_ref, xt_ref, *rest):
-        (*w_refs, o_ref, dots_ref, xw, sem) = rest
-        slot = _well_dma(pl, pltpu, starts_smem, x_hbm, xw, sem, win,
-                         n_tiles, bc)
-        t = pl.program_id(0)
-        xg = _block_gather(c_ref, xw[slot], tile, K, bc)
-        y = jnp.einsum("tkij,tkj->ti", v_ref[0], xg.astype(v_ref.dtype),
-                       preferred_element_type=out_dtype
-                       ).reshape(tile * br)
-        o_ref[0] = y.astype(o_ref.dtype)
-        ya = y.astype(acc_dtype)
-        p_yy = jnp.sum(ya * ya)
-        p_yx = jnp.sum(ya * xt_ref[0].astype(acc_dtype))
-
-        @pl.when(t == 0)
-        def _init():
-            for j in range(2 + has_w):
-                dots_ref[0, j] = jnp.zeros((), acc_dtype)
-
-        dots_ref[0, 0] += p_yy
-        dots_ref[0, 1] += p_yx
-        if has_w:
-            dots_ref[0, 2] += jnp.sum(ya * w_refs[0][0].astype(acc_dtype))
-
-    xp, vec_spec, grid_spec = _well_block_geometry(
-        x, win, bc, n_tiles, tile, K, br, len(vecs),
-        (pl.BlockSpec((1, tile * br),
-                      lambda t, starts: (t, np.int32(0))),
-         # explicit i32 map — see _well_geometry
-         pl.BlockSpec((1, 2 + has_w),
-                      lambda t, starts: (np.int32(0), np.int32(0)),
-                      memory_space=pltpu.SMEM)))
-    y, dots = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=(
-            jax.ShapeDtypeStruct((n_tiles, tile * br), out_dtype),
-            jax.ShapeDtypeStruct((1, 2 + has_w), acc_dtype),
-        ),
-        interpret=interpret,
-    )(window_starts, xp, cols_local, vals,
-      *(v.reshape(n_tiles, tile * br) for v in vecs))
-    yy = dots[0, 0].astype(out_dtype)
-    yx = dots[0, 1].astype(out_dtype)
-    yw = dots[0, 2].astype(out_dtype) if has_w else None
-    return y.reshape(n_pad)[:n_out * br], yy, yx, yw
-
-
-def windowed_ell_block_residual(window_starts, cols_local, vals, f, x,
-                                win, n_out, interpret: bool = False):
-    """r = f − A x in one pass (block windowed-ELL)."""
-    return windowed_ell_block_fused(window_starts, cols_local, vals, f, x,
-                                    None, "residual", win, n_out, interpret)
-
-
-def windowed_ell_block_scaled_correction(window_starts, cols_local, vals,
-                                         S, f, x, win, n_out,
-                                         interpret: bool = False):
-    """x + S ∘ (f − A x) in one pass — a block Jacobi/SPAI-0 sweep."""
-    return windowed_ell_block_fused(window_starts, cols_local, vals, f, x,
-                                    S, "correction", win, n_out, interpret)
 
 
 def tile_windows(A: CSR, tile: int):

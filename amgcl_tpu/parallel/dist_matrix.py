@@ -26,7 +26,7 @@ from jax.tree_util import register_pytree_node_class
 
 from amgcl_tpu.ops.csr import CSR
 from amgcl_tpu.ops.device import csr_to_dia
-from amgcl_tpu.parallel.compat import axis_size as _axis_size
+from jax.lax import axis_size as _axis_size
 from amgcl_tpu.parallel.mesh import ROWS_AXIS
 
 

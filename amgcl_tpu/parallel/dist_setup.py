@@ -407,7 +407,7 @@ def _compiled_alltoall(mesh, C, kind):
     """One jitted shard_map all_to_all for (nd, nd, C, ...) payloads."""
     import jax
     from jax import lax
-    from amgcl_tpu.parallel.compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     def run(idx, val):

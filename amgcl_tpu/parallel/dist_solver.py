@@ -32,7 +32,7 @@ from functools import lru_cache, partial
 import jax
 import jax.numpy as jnp
 from jax import lax
-from amgcl_tpu.parallel.compat import shard_map
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from amgcl_tpu.parallel.mesh import ROWS_AXIS

@@ -34,8 +34,8 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax import lax
-from amgcl_tpu.parallel.compat import shard_map, \
-    axis_size as _axis_size
+from jax import shard_map
+from jax.lax import axis_size as _axis_size
 from jax.sharding import NamedSharding, PartitionSpec as P
 from jax.tree_util import register_pytree_node_class
 

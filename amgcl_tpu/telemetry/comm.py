@@ -279,7 +279,7 @@ def _iter_leg(spmv, r, x, di, pipelined: bool, ablate: bool):
 
 def _dia_stages(A, mesh, pipelined: bool) -> List[Dict[str, Any]]:
     from jax.sharding import PartitionSpec as P
-    from amgcl_tpu.parallel.compat import shard_map
+    from jax import shard_map
     from amgcl_tpu.parallel.mesh import ROWS_AXIS
     from amgcl_tpu.parallel import dist_matrix as DM
     from amgcl_tpu.telemetry.compile_watch import watched_jit
@@ -344,7 +344,7 @@ def _dia_stages(A, mesh, pipelined: bool) -> List[Dict[str, Any]]:
 
 def _ell_stages(A, mesh, pipelined: bool) -> List[Dict[str, Any]]:
     from jax.sharding import PartitionSpec as P
-    from amgcl_tpu.parallel.compat import shard_map
+    from jax import shard_map
     from amgcl_tpu.parallel.mesh import ROWS_AXIS
     from amgcl_tpu.telemetry.compile_watch import watched_jit
 
@@ -404,7 +404,7 @@ def _psum_stage(mesh, n, dtype, elems: int) -> Dict[str, Any]:
     import jax.numpy as jnp
     from jax import lax
     from jax.sharding import PartitionSpec as P
-    from amgcl_tpu.parallel.compat import shard_map
+    from jax import shard_map
     from amgcl_tpu.parallel.mesh import ROWS_AXIS
     from amgcl_tpu.telemetry.compile_watch import watched_jit
 

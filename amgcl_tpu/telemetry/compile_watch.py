@@ -68,19 +68,11 @@ DECLARED_ENTRY_POINTS = (
     "ops.fused_down_sweep",
     "ops.fused_up_sweep",
     "ops.fused_vec",
-    "ops.gather_spmv",
-    "ops.gather_spmv_xla",
     "ops.level_setup",
     "ops.segment_galerkin",
     "ops.segment_spgemm",
     "ops.stencil_galerkin",
     "ops.transfer_smooth",
-    "ops.windowed_ell_block_fused",
-    "ops.windowed_ell_block_spmv",
-    "ops.windowed_ell_block_spmv_dots",
-    "ops.windowed_ell_fused",
-    "ops.windowed_ell_spmv",
-    "ops.windowed_ell_spmv_dots",
     "parallel.dist_amg_solve",
     "parallel.dist_cg",
     "parallel.dist_cg_pipelined",

@@ -593,24 +593,6 @@ SETUP_CONTRACTS = {
 }
 
 
-#: census CONTRACT of the gather-SpMV pair (ops/pallas_gather.py,
-#: audited statically by analysis/jaxpr_audit.audit_gather): the
-#: per-slot unrolled kernel and its take-along XLA fallback are a pure
-#: streaming SpMV — no host callbacks (a callback inside the Krylov
-#: body would serialize every iteration on a device->host round trip),
-#: no collectives (single-device operator; the sharded SpMV lives in
-#: parallel/), and no float-width casts on matrix-sized values (the
-#: kernel accumulates in the value dtype; widening happens only at the
-#: declared ``preferred_element_type`` output seam). A violation fails
-#: `python -m amgcl_tpu.analysis`, not a chip session.
-GATHER_CONTRACTS = {
-    "ops.gather_spmv":
-        {"host_callbacks": 0, "collectives": 0, "narrowing_casts": 0},
-    "ops.gather_spmv_xla":
-        {"host_callbacks": 0, "collectives": 0, "narrowing_casts": 0},
-}
-
-
 # ---------------------------------------------------------------------------
 # setup-phase cost model + stage attribution
 # ---------------------------------------------------------------------------

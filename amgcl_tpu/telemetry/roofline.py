@@ -52,10 +52,13 @@ import numpy as np
 
 from amgcl_tpu.telemetry import ledger as _ledger
 
-#: (device_kind substring, HBM GB/s, dense-peak FLOP/s) — public figures;
-#: the FLOPs column is the dense-unit (MXU) peak, i.e. an upper bound a
-#: sparse kernel will not approach: the roofline's compute ceiling, not a
-#: target. Substring order matters (v5p before v5).
+#: (device_kind substring, HBM GB/s, dense-peak FLOP/s) per chip, from the
+#: Google Cloud TPU documentation's per-version pages (e.g. "TPU v5e":
+#: 819 GB/s HBM, 197 TFLOP/s bf16). The FLOPs column is the dense-unit
+#: (MXU) bf16 peak, an upper bound a sparse kernel will not approach: the
+#: roofline's compute ceiling, not a target. The one table of peaks in the
+#: repo; a TPU whose device_kind is not in it is an error. Substring order
+#: matters (v5p before v5).
 TPU_PEAKS = [
     ("v6", 1640.0, 918e12),
     ("v5p", 2765.0, 459e12),
@@ -121,8 +124,10 @@ def device_peaks(refresh: bool = False) -> Dict[str, Any]:
     """``{"gbps", "flops", "platform", "device_kind", "source"}`` for the
     default device. Resolution order per number: env override
     (``AMGCL_TPU_PEAK_GBPS`` in GB/s, ``AMGCL_TPU_PEAK_FLOPS`` in
-    FLOP/s), the TPU table, a one-time measured fallback (cached
-    process-global — the stream/matmul probes cost ~0.1 s once)."""
+    FLOP/s), the TPU table, and off the TPU a one-time measured value
+    (cached process-global — the stream/matmul probes cost ~0.1 s once).
+    A TPU whose ``device_kind`` is not in :data:`TPU_PEAKS` raises: a
+    measured rate is not a peak."""
     global _peaks_cache
     if _peaks_cache is not None and not refresh:
         return _peaks_cache
@@ -151,6 +156,11 @@ def device_peaks(refresh: bool = False) -> Dict[str, Any]:
                 if out["flops"] is None:
                     out["flops"], out["source"]["flops"] = flops, "table"
                 break
+        else:
+            if out["gbps"] is None or out["flops"] is None:
+                raise ValueError("no published peaks for TPU device_kind "
+                                 "%r in roofline.TPU_PEAKS"
+                                 % out["device_kind"])
     if out["gbps"] is None:
         try:
             out["gbps"] = round(_measure_stream_gbps(), 2)

@@ -1,7 +1,7 @@
 """JSONL metrics sink — one JSON object per line, shared by bench.py,
 cli.py, make_solver and the distributed solvers.
 
-Schema convention (shared with BENCH_*.json / PROGRESS.jsonl): flat JSON
+Schema convention (shared with BENCH_*.json): flat JSON
 objects; every stamped record carries ``ts`` (unix seconds) and ``ts_iso``;
 solver-originated records carry an ``event`` field ("solve", "setup",
 "profile", "bench", "tier1_check", "health", "doctor", ...) plus the
@@ -64,9 +64,8 @@ def stamp(record: Dict[str, Any], commit: Optional[str] = None,
     on-disk artifact stays byte-compatible."""
     rec = dict(record)
     rec.setdefault("ts", time.time() if now is None else now)
-    # ts_iso always renders the record's ts — a pre-stamped ts (e.g. the
-    # opportunistic bench loop stamps at cycle start) must not disagree
-    # with it
+    # ts_iso always renders the record's ts — a pre-stamped ts must not
+    # disagree with it
     rec.setdefault("ts_iso", time.strftime("%Y-%m-%dT%H:%M:%SZ",
                                            time.gmtime(rec["ts"])))
     if commit is not None:
@@ -86,8 +85,8 @@ def git_commit(repo: str) -> Optional[str]:
 
 
 def write_json_atomic(path: str, record: Dict[str, Any]) -> None:
-    """Single-object JSON file via tmp + rename (the BENCH_LAST_GOOD.json
-    write path: a reader never sees a torn file). No non-finite cleaning —
+    """Single-object JSON file via tmp + rename (a reader never sees a
+    torn file). No non-finite cleaning —
     this path reproduces the historical bench artifact byte-for-byte."""
     tmp = path + ".tmp"
     with open(tmp, "w") as f:
@@ -106,9 +105,9 @@ def max_sink_bytes() -> int:
 
 class JsonlSink:
     """Append-mode JSONL writer. ``path`` XOR ``stream``; file sinks
-    open/write/close per record so concurrent emitters (supervisor +
-    worker, or the opportunistic bench loop) interleave at line
-    granularity and a crash never loses buffered lines.
+    open/write/close per record so concurrent emitters (processes or
+    threads) interleave at line granularity and a crash never loses
+    buffered lines.
 
     ``clean_records=False`` opts out of the non-finite-float cleaning for
     surfaces with a pre-existing schema contract (bench.py's stdout line,

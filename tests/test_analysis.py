@@ -293,51 +293,6 @@ def test_audit_detects_host_callback_in_loop():
 
 
 # ===========================================================================
-# jaxpr auditor: gather-SpMV census (ops/pallas_gather.py)
-# ===========================================================================
-
-def test_audit_gather_records_clean():
-    """Both gather-SpMV entries trace clean on CPU (interpret seam) and
-    pass the GATHER_CONTRACTS census: no host callbacks, no
-    collectives, no narrowing casts on matrix-sized values."""
-    recs = ja.audit_gather()
-    assert {r["entry"] for r in recs} == {"ops.gather_spmv",
-                                          "ops.gather_spmv_xla"}
-    for rec in recs:
-        assert "skipped" not in rec, rec
-        assert [f for f in ja.check_gather(rec)
-                if f["severity"] == "error"] == [], rec
-
-
-def test_audit_gather_detects_injected_downcast():
-    """Negative injection: a gather-SpMV-shaped program that round-trips
-    the values through f64 and narrows back plants a matrix-sized
-    downcast; check_gather must fail the dtype pass."""
-    from amgcl_tpu.ops import pallas_gather as pg
-    n_tiles, tile, K = 2, 1024, 4
-    n = n_tiles * tile
-    starts = jnp.zeros(n_tiles, jnp.int32)
-    cols = jnp.zeros((n_tiles, tile, K), jnp.int32)
-    vals = jnp.ones((n_tiles, tile, K), jnp.float32)
-    x = jnp.ones(n, jnp.float32)
-
-    def poisoned(s, c, v, xv):
-        y = pg.gather_spmv_xla(s, c, v.astype(jnp.float64), xv,
-                               n_out=n)
-        return y.astype(jnp.float32)          # the injected narrowing
-
-    jx = jax.make_jaxpr(poisoned)(starts, cols, vals, x)
-    rec = {"entry": "ops.gather_spmv_xla", "n": n,
-           "collectives": ja.collective_census(jx.jaxpr),
-           "casts": [c for c in ja.dtype_casts(jx.jaxpr, 1)
-                     if c["elements"] >= n],
-           "host_callbacks": ja.host_callbacks(jx.jaxpr)}
-    errors = [f for f in ja.check_gather(rec)
-              if f["severity"] == "error" and f["pass"] == "dtype"]
-    assert errors, rec["casts"]
-
-
-# ===========================================================================
 # jaxpr auditor: distributed collective census
 # ===========================================================================
 
@@ -368,7 +323,7 @@ def test_audit_detects_extra_psum():
     """Negative injection: a pipelined-CG-shaped body with a second
     psum (the regression the contract exists for) must fail the
     census."""
-    from amgcl_tpu.parallel.compat import shard_map
+    from jax import shard_map
     from amgcl_tpu.parallel.mesh import make_mesh, ROWS_AXIS
     from jax.sharding import PartitionSpec as P
 

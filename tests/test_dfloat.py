@@ -114,6 +114,23 @@ def test_refine_df32_bicgstab():
     assert tr < 2e-7, tr
 
 
+def test_refine_df32_selfcheck_fallback_is_recorded(monkeypatch):
+    """A failed df32 self-check falls back to float64 refinement, warns,
+    and says so on the bundle (refine_fallback)."""
+    from amgcl_tpu.models.make_solver import make_solver
+    from amgcl_tpu.models.amg import AMGParams
+    from amgcl_tpu.solver.cg import CG
+    A, _ = poisson3d(8)
+    ok = make_solver(A, AMGParams(dtype=jnp.float32), CG(), refine=1,
+                     refine_dtype="df32")
+    assert ok.refine_mode == "df32" and not ok.refine_fallback
+    monkeypatch.setattr(make_solver, "_df32_selfcheck", lambda self, A: False)
+    with pytest.warns(UserWarning, match="self-check"):
+        s = make_solver(A, AMGParams(dtype=jnp.float32), CG(), refine=1,
+                        refine_dtype="df32")
+    assert s.refine_mode == "float64" and s.refine_fallback
+
+
 def test_refine_df32_needs_dia():
     from amgcl_tpu.models.make_solver import make_solver
     from amgcl_tpu.models.amg import AMGParams

@@ -228,7 +228,7 @@ def test_audit_comm_negative_injection(mesh8):
     import jax
     from jax import lax
     from jax.sharding import PartitionSpec as P
-    from amgcl_tpu.parallel.compat import shard_map
+    from jax import shard_map
     from amgcl_tpu.parallel.mesh import ROWS_AXIS
     from amgcl_tpu.analysis import jaxpr_audit as ja
 

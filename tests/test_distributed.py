@@ -23,7 +23,7 @@ def test_dist_spmv_matches_host(mesh8):
     M = DistDiaMatrix.from_csr(A, mesh8, jnp.float64)
     x = np.random.RandomState(0).rand(A.nrows)
     from jax.sharding import NamedSharding, PartitionSpec as P
-    from amgcl_tpu.parallel.compat import shard_map
+    from jax import shard_map
     fn = shard_map(M.shard_mv, mesh=mesh8,
                    in_specs=(P(None, "rows"), P("rows")),
                    out_specs=P("rows"), check_vma=False)
@@ -102,7 +102,7 @@ def test_dist_cg_pipelined_env_dispatch(mesh8, monkeypatch):
 
 def test_dist_ell_spmv_matches_host(mesh8):
     from amgcl_tpu.parallel.dist_ell import build_dist_ell
-    from amgcl_tpu.parallel.compat import shard_map
+    from jax import shard_map
     from jax.sharding import NamedSharding, PartitionSpec as P
     A, _ = poisson3d(11)   # 1331 rows: not divisible by 8 -> padding path
     M = build_dist_ell(A, mesh8, jnp.float64)

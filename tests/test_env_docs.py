@@ -61,6 +61,5 @@ def test_table_covers_new_knobs():
                 "AMGCL_TPU_MEMWATCH_LEAK_BYTES",
                 "AMGCL_TPU_MEMWATCH_TIMEOUT",
                 "AMGCL_TPU_GATE_MEMDRIFT", "AMGCL_TPU_FARM_HEADROOM",
-                "AMGCL_TPU_REORDER", "AMGCL_TPU_GATE_XRAY",
-                "AMGCL_TPU_GATHER_KERNEL"):
+                "AMGCL_TPU_REORDER", "AMGCL_TPU_GATE_XRAY"):
         assert var in documented, var

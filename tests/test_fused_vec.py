@@ -198,7 +198,7 @@ def test_psum_seam_merges_reductions():
     """Under shard_map with the psum-marked distributed dot, the fused
     primitives return globally-reduced values (matching the serial
     math), via ONE stacked psum."""
-    from amgcl_tpu.parallel.compat import shard_map
+    from jax import shard_map
     from amgcl_tpu.parallel.mesh import make_mesh
     from amgcl_tpu.parallel.dist_matrix import dist_inner_product
     from jax.sharding import PartitionSpec as P
@@ -239,7 +239,7 @@ def test_spmv_dots_accepts_psum_seam():
     """ISSUE 5 satellite: spmv_dots with the psum-marked distributed dot
     returns globally-reduced dots (local-shard fusion + one collective)
     instead of falling back to the unfused per-dot seam calls."""
-    from amgcl_tpu.parallel.compat import shard_map
+    from jax import shard_map
     from amgcl_tpu.parallel.mesh import make_mesh
     from amgcl_tpu.parallel.dist_matrix import dist_inner_product
     from jax.sharding import PartitionSpec as P

@@ -12,7 +12,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 import pytest
-from amgcl_tpu.parallel.compat import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from amgcl_tpu.ops.csr import CSR

@@ -1,7 +1,7 @@
 """Memory observatory (ISSUE 18): measured-vs-ledger joins per device
 format, ownership attribution through eviction, the leak-cycle
 selftest and its negative injection, RESOURCE_EXHAUSTED classification
-into the typed AllocationError taxonomy, OOM flight forensics
+into the typed AllocationError hierarchy, OOM flight forensics
 (timeline + top-owner table in the bundle manifest), the doctor
 ``memory=`` fold, measured farm headroom, and the live gauges."""
 
@@ -187,7 +187,7 @@ def test_is_resource_exhausted_classification():
     # typed faults never re-classify (no double wrapping)
     assert not faults.is_resource_exhausted(
         faults.AllocationError("RESOURCE_EXHAUSTED"))
-    # the taxonomy: admission refusals ARE allocation errors
+    # the hierarchy: admission refusals ARE allocation errors
     assert issubclass(faults.AdmissionError, faults.AllocationError)
     assert issubclass(faults.AllocationError, faults.FaultError)
 

@@ -3,13 +3,14 @@ APPLIED at build time — hierarchy + transfers absorb it, rhs/x0 are
 permuted in and x un-permuted out — and must be semantically invisible:
 solution parity in f64, batched (n, B) pass-through, rebuild/farm plan
 reuse through the fingerprint cache, ledger-driven format winners
-flipping on the permuted-banded fixture, and gather-SpMV agreement with
-its XLA fallback."""
+flipping on the permuted-banded fixture, and the windowed-ELL SpMV on
+the reordered operator."""
 
 import json
 import os
 
 import numpy as np
+import jax
 import jax.numpy as jnp
 import pytest
 
@@ -17,7 +18,6 @@ from amgcl_tpu.models.amg import AMG, AMGParams
 from amgcl_tpu.models.make_solver import make_solver
 from amgcl_tpu.ops import device as dev
 from amgcl_tpu.ops.csr import CSR
-from amgcl_tpu.ops import pallas_gather as pg
 from amgcl_tpu.ops.unstructured import csr_to_windowed_ell
 from amgcl_tpu.solver.cg import CG
 from amgcl_tpu.telemetry import structure as st
@@ -184,65 +184,37 @@ def test_decision_records_reorder_provenance(monkeypatch):
     assert prov["fingerprint"] == st.fingerprint(A)
 
 
-# -- gather-SpMV kernel vs XLA fallback --------------------------------------
+# -- windowed-ELL SpMV on the reordered operator -----------------------------
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.float64])
-def test_gather_spmv_agreement_interpret(dtype):
+def test_windowed_ell_spmv_reordered(dtype):
     _, A0, _ = _fixture(n=2048)
     W = csr_to_windowed_ell(A0, dtype)
     assert W is not None and W.block == (1, 1)
-    rng = np.random.RandomState(2)
-    x = jnp.asarray(rng.rand(A0.ncols), dtype)
-    y_ref = np.asarray(pg.gather_spmv_xla(
-        W.window_starts, W.cols_local, W.vals, x, W.shape[0]))
-    y = np.asarray(pg.gather_spmv(
-        W.window_starts, W.cols_local, W.vals, x, W.win, W.shape[0],
-        interpret=True))
-    tol = 1e-12 if dtype == jnp.float64 else 1e-5
-    np.testing.assert_allclose(y, y_ref, rtol=tol,
-                               atol=tol * np.abs(y_ref).max())
-    # and both against the host truth
+    x = jnp.asarray(np.random.RandomState(2).rand(A0.ncols), dtype)
+    y = np.asarray(W.mv(x), np.float64)
     y_host = A0.spmv(np.asarray(x, np.float64))
-    np.testing.assert_allclose(
-        y_ref, y_host, rtol=1e-4 if dtype == jnp.float32 else 1e-12)
+    tol = 1e-12 if dtype == jnp.float64 else 1e-5
+    np.testing.assert_allclose(y, y_host, rtol=tol,
+                               atol=tol * np.abs(y_host).max())
 
 
-def test_gather_dispatch_and_kill_switch(monkeypatch):
+def test_windowed_ell_spmv_jit_and_stacked():
+    """The jitted SpMV equals the eager one, and a stacked (n, B) operand
+    through the device seam equals the per-column products."""
     _, A0, _ = _fixture(n=2048)
     W = csr_to_windowed_ell(A0, jnp.float32)
-    x = jnp.asarray(np.random.RandomState(3).rand(A0.ncols), jnp.float32)
-    monkeypatch.setenv("AMGCL_TPU_GATHER_KERNEL", "0")
-    assert pg.maybe_gather_spmv(W, x) is None
-    monkeypatch.setenv("AMGCL_TPU_GATHER_KERNEL", "auto")
-    monkeypatch.setenv("AMGCL_TPU_PALLAS_INTERPRET", "1")
-    y = pg.maybe_gather_spmv(W, x)
-    assert y is not None
-    y_ref = np.asarray(pg.gather_spmv_xla(
-        W.window_starts, W.cols_local, W.vals, x, W.shape[0]))
-    np.testing.assert_allclose(np.asarray(y), y_ref, rtol=1e-5,
-                               atol=1e-5 * np.abs(y_ref).max())
-    # mv() rides the same seam end to end
-    y_mv = np.asarray(W.mv(x))
-    np.testing.assert_allclose(y_mv, y_ref, rtol=1e-5,
-                               atol=1e-5 * np.abs(y_ref).max())
-
-
-@pytest.mark.skipif(
-    __import__("jax").default_backend() != "tpu",
-    reason="compiled gather kernel needs a real TPU")
-def test_gather_spmv_agreement_compiled():
-    _, A0, _ = _fixture(n=4096)
-    W = csr_to_windowed_ell(A0, jnp.float32)
-    assert pg.gather_kernel_supported(W.win, W.cols_local.shape[2],
-                                      W.dtype)
-    x = jnp.asarray(np.random.RandomState(4).rand(A0.ncols), jnp.float32)
-    y = np.asarray(pg.gather_spmv(
-        W.window_starts, W.cols_local, W.vals, x, W.win, W.shape[0],
-        interpret=False))
-    y_ref = np.asarray(pg.gather_spmv_xla(
-        W.window_starts, W.cols_local, W.vals, x, W.shape[0]))
-    np.testing.assert_allclose(y, y_ref, rtol=1e-5,
-                               atol=1e-5 * np.abs(y_ref).max())
+    X = jnp.asarray(np.random.RandomState(3).rand(A0.ncols, 3),
+                    jnp.float32)
+    y_eager = np.asarray(W.mv(X[:, 0]))
+    y_jit = np.asarray(jax.jit(lambda M, v: M.mv(v))(W, X[:, 0]))
+    np.testing.assert_allclose(y_jit, y_eager, rtol=1e-6,
+                               atol=1e-6 * np.abs(y_eager).max())
+    Y = np.asarray(dev.spmv(W, X))
+    for j in range(3):
+        yj = np.asarray(W.mv(X[:, j]))
+        np.testing.assert_allclose(Y[:, j], yj, rtol=1e-6,
+                                   atol=1e-6 * np.abs(yj).max())
 
 
 # -- flight-recorder replay parity under reorder -----------------------------

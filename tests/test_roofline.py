@@ -60,6 +60,41 @@ def test_device_peaks_env_override(monkeypatch):
         rl.device_peaks(refresh=True)      # drop the override from cache
 
 
+class _FakeTpu:
+    platform = "tpu"
+
+    def __init__(self, kind):
+        self.device_kind = kind
+
+
+@pytest.mark.parametrize("kind,gbps,flops", [("TPU v5 lite", 819.0, 197e12),
+                                             ("TPU v5e", 819.0, 197e12)])
+def test_device_peaks_tpu_table(monkeypatch, kind, gbps, flops):
+    import jax
+    monkeypatch.setattr(jax, "devices", lambda *a: [_FakeTpu(kind)])
+    try:
+        pk = rl.device_peaks(refresh=True)
+        assert (pk["gbps"], pk["flops"]) == (gbps, flops)
+        assert pk["source"] == {"gbps": "table", "flops": "table"}
+    finally:
+        monkeypatch.undo()
+        rl.device_peaks(refresh=True)
+
+
+def test_device_peaks_unknown_tpu_raises(monkeypatch):
+    """A TPU missing from the table is an error, never a measured rate
+    standing in for its peak."""
+    import jax
+    monkeypatch.setattr(jax, "devices",
+                        lambda *a: [_FakeTpu("TPU v99 unknown")])
+    try:
+        with pytest.raises(ValueError, match="no published peaks"):
+            rl.device_peaks(refresh=True)
+    finally:
+        monkeypatch.undo()
+        rl.device_peaks(refresh=True)
+
+
 # ---------------------------------------------------------------------------
 # measured-vs-model join
 # ---------------------------------------------------------------------------

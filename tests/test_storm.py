@@ -403,7 +403,7 @@ def test_unhealthy_solves_excluded_from_goodput():
     assert "latency_ms" not in summ       # no ok rows, no percentiles
 
 
-def test_classify_exc_taxonomy():
+def test_classify_exc_classes():
     class RequestTimeout(Exception):
         pass
     assert S._classify_exc(queue.Full()) == "shed"

@@ -1,5 +1,6 @@
-"""Windowed-ELL unstructured SpMV: packing, XLA path, Pallas interpret
-path, and an end-to-end AMG solve on an FE-style irregular matrix
+"""Windowed-ELL unstructured SpMV: packing, the device seams (SpMV,
+residual, smoother, fused dots), and end-to-end AMG solves on FE-style
+irregular matrices
 (reference capability: general-sparsity device SpMV,
 amgcl/backend/cuda.hpp:60-843)."""
 
@@ -10,9 +11,8 @@ import pytest
 from amgcl_tpu.ops.csr import CSR
 from amgcl_tpu.ops import device as dev
 from amgcl_tpu.ops.unstructured import (
-    WindowedEllMatrix, csr_to_windowed_ell, windowed_ell_spmv,
-    windowed_ell_residual, windowed_ell_scaled_correction,
-    windowed_ell_spmv_dots, fe_like_problem, _TILE, _WIN_ALIGN)
+    WindowedEllMatrix, csr_to_windowed_ell, fe_like_problem, _TILE,
+    _WIN_ALIGN)
 from amgcl_tpu.utils.adapters import cuthill_mckee, permute
 
 
@@ -29,20 +29,18 @@ def test_windowed_ell_matches_host_spmv():
     assert W is not None
     x = np.random.RandomState(0).rand(A.nrows)
     y_ref = Ap.spmv(x)
-    y = np.asarray(W._mv_xla(jnp.asarray(x)))
+    y = np.asarray(W.mv(jnp.asarray(x)))
     np.testing.assert_allclose(y, y_ref, rtol=1e-12)
 
 
-def test_windowed_ell_pallas_interpret_matches():
+def test_windowed_ell_f32_matches_host_spmv():
     A, _ = _small_fe(n=2500, seed=2)
     perm = cuthill_mckee(A)
     Ap = permute(A, perm)
     W = csr_to_windowed_ell(Ap, jnp.float32)
     x = np.random.RandomState(1).rand(A.nrows).astype(np.float32)
     y_ref = Ap.spmv(x.astype(np.float64))
-    y = np.asarray(windowed_ell_spmv(
-        W.window_starts, W.cols_local, W.vals, jnp.asarray(x),
-        W.win, W.shape[0], interpret=True))
+    y = np.asarray(W.mv(jnp.asarray(x)))
     # scale-aware atol: the 1/h² fixture weights span ~3 orders, so rows
     # with catastrophic cancellation bound the f32 error absolutely (by
     # ~max|y|·eps·√k), not relatively
@@ -87,51 +85,46 @@ def _windowed_fixture(n=2500, seed=7):
     return Ap, W, x, f, w
 
 
-def test_windowed_fused_residual_interpret_matches():
+def test_windowed_residual_seam():
     Ap, W, x, f, _ = _windowed_fixture()
     r_ref = f - Ap.spmv(x.astype(np.float64))
-    r = np.asarray(windowed_ell_residual(
-        W.window_starts, W.cols_local, W.vals, jnp.asarray(f),
-        jnp.asarray(x), W.win, W.shape[0], interpret=True))
+    r = np.asarray(dev.residual(jnp.asarray(f), W, jnp.asarray(x)))
     np.testing.assert_allclose(r, r_ref, rtol=5e-4, atol=5e-4)
 
 
-def test_windowed_fused_correction_interpret_matches():
+def test_windowed_scaled_correction_composes():
+    """Windowed ELL has no fused correction kernel: the seam declines
+    and the smoother composes residual and scale."""
+    from amgcl_tpu.relaxation.base import ScaledResidualSmoother
     Ap, W, x, f, w = _windowed_fixture(seed=8)
+    assert dev.scaled_correction(W, jnp.asarray(w), jnp.asarray(f),
+                                 jnp.asarray(x)) is None
     ref = x + w * (f - Ap.spmv(x.astype(np.float64)))
-    got = np.asarray(windowed_ell_scaled_correction(
-        W.window_starts, W.cols_local, W.vals, jnp.asarray(w),
-        jnp.asarray(f), jnp.asarray(x), W.win, W.shape[0],
-        interpret=True))
+    got = np.asarray(ScaledResidualSmoother(jnp.asarray(w)).apply_pre(
+        W, jnp.asarray(f), jnp.asarray(x)))
     np.testing.assert_allclose(got, ref, rtol=5e-4, atol=5e-4)
 
 
-def test_windowed_fused_spmv_dots_interpret_matches():
+def test_windowed_spmv_dots_seam():
     Ap, W, x, _, w = _windowed_fixture(seed=9)
     y_ref = Ap.spmv(x.astype(np.float64))
-    y, yy, yx, yw = windowed_ell_spmv_dots(
-        W.window_starts, W.cols_local, W.vals, jnp.asarray(x),
-        jnp.asarray(w), win=W.win, n_out=W.shape[0], interpret=True)
+    y, yy, yx, yw = dev.spmv_dots(W, jnp.asarray(x), jnp.asarray(w))
     np.testing.assert_allclose(np.asarray(y), y_ref, rtol=5e-4, atol=5e-4)
     np.testing.assert_allclose(float(yy), y_ref @ y_ref, rtol=1e-3)
     np.testing.assert_allclose(float(yx), y_ref @ x, rtol=1e-3)
     np.testing.assert_allclose(float(yw), y_ref @ w, rtol=1e-3)
     # w=None leg returns yw=None and the same pairs
-    y2, yy2, yx2, yw2 = windowed_ell_spmv_dots(
-        W.window_starts, W.cols_local, W.vals, jnp.asarray(x),
-        None, win=W.win, n_out=W.shape[0], interpret=True)
+    y2, yy2, yx2, yw2 = dev.spmv_dots(W, jnp.asarray(x))
     assert yw2 is None
     np.testing.assert_allclose(float(yx2), float(yx), rtol=1e-6)
 
 
-def test_windowed_fused_wiring_through_seams(monkeypatch):
-    """The production seams (dev.residual / dev.spmv_dots / smoother
-    apply_pre) must route WindowedEllMatrix through the fused kernels
-    under the CI interpret hook — same wiring discipline as the DIA
-    tiers (tests/test_sweep.py)."""
+def test_windowed_seams_under_interpret_hook(monkeypatch):
+    """With the CI interpret hook on, the windowed-ELL seams still take
+    the XLA path (there is no kernel to route to) and agree with the
+    host."""
     monkeypatch.setenv("AMGCL_TPU_PALLAS_INTERPRET", "1")
     Ap, W, x, f, w = _windowed_fixture(seed=10)
-    assert W._pallas_mode(jnp.asarray(x)) is True
     r = np.asarray(dev.residual(jnp.asarray(f), W, jnp.asarray(x)))
     np.testing.assert_allclose(
         r, f - Ap.spmv(x.astype(np.float64)), rtol=5e-4, atol=5e-4)
@@ -159,69 +152,42 @@ def _block_fixture(n_pt=1500, b=3, seed=12):
     return Ab, W, x, f, S
 
 
-def test_windowed_block_spmv_interpret_matches():
-    from amgcl_tpu.ops.unstructured import windowed_ell_block_spmv
+def test_windowed_block_spmv_matches_host():
     Ab, W, x, _, _ = _block_fixture()
     y_ref = Ab.unblock().spmv(x.astype(np.float64))
-    y = np.asarray(windowed_ell_block_spmv(
-        W.window_starts, W.cols_local, W.vals, jnp.asarray(x),
-        W.win, W.shape[0], interpret=True))
+    y = np.asarray(W.mv(jnp.asarray(x)))
     np.testing.assert_allclose(y, y_ref, rtol=5e-4, atol=5e-4)
-    # XLA fallback agrees too
-    np.testing.assert_allclose(np.asarray(W._mv_xla(jnp.asarray(x))),
-                               y_ref, rtol=5e-4, atol=5e-4)
 
 
-def test_windowed_block_fused_interpret_matches():
-    from amgcl_tpu.ops.unstructured import (
-        windowed_ell_block_residual, windowed_ell_block_scaled_correction)
+def test_windowed_block_residual_and_correction():
+    from amgcl_tpu.relaxation.base import ScaledResidualSmoother
     Ab, W, x, f, S = _block_fixture(seed=13)
     ax = Ab.unblock().spmv(x.astype(np.float64))
     r_ref = f - ax
-    r = np.asarray(windowed_ell_block_residual(
-        W.window_starts, W.cols_local, W.vals, jnp.asarray(f),
-        jnp.asarray(x), W.win, W.shape[0], interpret=True))
+    r = np.asarray(dev.residual(jnp.asarray(f), W, jnp.asarray(x)))
     np.testing.assert_allclose(r, r_ref, rtol=5e-4, atol=5e-4)
     b = W.block[0]
     corr_ref = x + np.einsum(
         "nij,nj->ni", S, r_ref.reshape(-1, b)).reshape(-1)
-    got = np.asarray(windowed_ell_block_scaled_correction(
-        W.window_starts, W.cols_local, W.vals, jnp.asarray(S),
-        jnp.asarray(f), jnp.asarray(x), W.win, W.shape[0],
-        interpret=True))
+    sm = ScaledResidualSmoother(jnp.asarray(S), block=b)
+    got = np.asarray(sm.apply_pre(W, jnp.asarray(f), jnp.asarray(x)))
     np.testing.assert_allclose(got, corr_ref, rtol=5e-4, atol=5e-4)
 
 
-def test_windowed_block_spmv_dots_interpret_matches(monkeypatch):
-    import amgcl_tpu.ops.unstructured as unstruct
+def test_windowed_block_spmv_dots_seam():
     Ab, W, x, _, _ = _block_fixture(seed=16)
-    rng = np.random.RandomState(16)
-    w = rng.rand(x.shape[0]).astype(np.float32)
+    w = np.random.RandomState(16).rand(x.shape[0]).astype(np.float32)
     y_ref = Ab.unblock().spmv(x.astype(np.float64))
-    y, yy, yx, yw = unstruct.windowed_ell_block_spmv_dots(
-        W.window_starts, W.cols_local, W.vals, jnp.asarray(x),
-        jnp.asarray(w), win=W.win, n_out=W.shape[0], interpret=True)
+    y, yy, yx, yw = dev.spmv_dots(W, jnp.asarray(x), jnp.asarray(w))
     np.testing.assert_allclose(np.asarray(y), y_ref, rtol=5e-4, atol=5e-4)
     np.testing.assert_allclose(float(yy), y_ref @ y_ref, rtol=1e-3)
     np.testing.assert_allclose(float(yx), y_ref @ x, rtol=1e-3)
     np.testing.assert_allclose(float(yw), y_ref @ w, rtol=1e-3)
-    # the seam must actually REACH the block kernel under the interpret
-    # hook (numeric equality alone also holds on the mv fallback)
-    monkeypatch.setenv("AMGCL_TPU_PALLAS_INTERPRET", "1")
-    calls = []
-    real = unstruct.windowed_ell_block_spmv_dots
-    monkeypatch.setattr(
-        unstruct, "windowed_ell_block_spmv_dots",
-        lambda *a, **k: calls.append(1) or real(*a, **k))
-    y2, yy2, yx2, yw2 = dev.spmv_dots(W, jnp.asarray(x), jnp.asarray(w))
-    assert calls, "seam did not dispatch the block dots kernel"
-    np.testing.assert_allclose(float(yx2), float(yx), rtol=1e-5)
 
 
-def test_windowed_block_wiring_through_seams(monkeypatch):
+def test_windowed_block_seams_under_interpret_hook(monkeypatch):
     monkeypatch.setenv("AMGCL_TPU_PALLAS_INTERPRET", "1")
     Ab, W, x, f, S = _block_fixture(seed=14)
-    assert W._pallas_mode(jnp.asarray(x)) is True
     r = np.asarray(dev.residual(jnp.asarray(f), W, jnp.asarray(x)))
     ax = Ab.unblock().spmv(x.astype(np.float64))
     np.testing.assert_allclose(r, f - ax, rtol=5e-4, atol=5e-4)
@@ -234,10 +200,9 @@ def test_windowed_block_wiring_through_seams(monkeypatch):
     np.testing.assert_allclose(got, ref, rtol=5e-4, atol=5e-4)
 
 
-def test_block_solver_windowed_end_to_end(monkeypatch):
+def test_block_solver_windowed_end_to_end():
     """make_block_solver on an RCM-banded problem: the block windowed-ELL
-    device format carries the whole solve under the interpret hook."""
-    monkeypatch.setenv("AMGCL_TPU_PALLAS_INTERPRET", "1")
+    device format carries the whole solve."""
     from amgcl_tpu.models.make_solver import make_solver
     from amgcl_tpu.models.amg import AMGParams
     from amgcl_tpu.solver.bicgstab import BiCGStab
@@ -255,30 +220,26 @@ def test_block_solver_windowed_end_to_end(monkeypatch):
     assert np.linalg.norm(r) / np.linalg.norm(rhs_p) < 1e-6
 
 
-def test_windowed_bf16_values_interpret():
-    """bfloat16 operator values through the windowed kernels (the HBM-
-    halving hierarchy option): packing, SpMV, and fused residual stay
-    within bf16 accuracy of the f64 reference."""
+def test_windowed_bf16_values():
+    """bfloat16 operator values in windowed ELL (the HBM-halving
+    hierarchy option): packing, SpMV, and residual stay within bf16
+    accuracy of the f64 reference."""
     Ap, _, x, f, _ = _windowed_fixture(seed=17)
     Wb = csr_to_windowed_ell(Ap, jnp.bfloat16)
     assert Wb is not None and Wb.dtype == jnp.bfloat16
     y_ref = Ap.spmv(x.astype(np.float64))
-    y = np.asarray(windowed_ell_spmv(
-        Wb.window_starts, Wb.cols_local, Wb.vals, jnp.asarray(x),
-        Wb.win, Wb.shape[0], interpret=True), np.float64)
+    y = np.asarray(Wb.mv(jnp.asarray(x)), np.float64)
     denom = np.abs(y_ref).max()
     assert np.abs(y - y_ref).max() / denom < 3e-2      # bf16 epsilon
-    r = np.asarray(windowed_ell_residual(
-        Wb.window_starts, Wb.cols_local, Wb.vals, jnp.asarray(f),
-        jnp.asarray(x), Wb.win, Wb.shape[0], interpret=True), np.float64)
+    r = np.asarray(dev.residual(jnp.asarray(f), Wb, jnp.asarray(x)),
+                   np.float64)
     assert np.abs(r - (f - y_ref)).max() / denom < 3e-2
 
 
 def test_transfers_take_windowed_format():
     """Hierarchy P/R go through auto format selection: on an RCM-banded
     problem with explicit transfers (Ruge-Stuben) they must pick the
-    windowed-ELL device format, riding the same Pallas SpMV as the level
-    operators."""
+    windowed-ELL device format, like the level operators."""
     from amgcl_tpu.models.amg import AMG, AMGParams
     from amgcl_tpu.coarsening.ruge_stuben import RugeStuben
     A, _ = _small_fe(n=6000, seed=18)
