@@ -25,7 +25,7 @@ from amgcl_tpu.coarsening.smoothed_aggregation import SmoothedAggregation
 from amgcl_tpu.coarsening.stall import CoarseningStall
 from amgcl_tpu.relaxation.spai0 import Spai0
 from amgcl_tpu.solver.direct import DenseDirectSolver
-from amgcl_tpu.telemetry.tracing import phase, setup_scope
+from amgcl_tpu.telemetry.tracing import phase, setup_scope, span
 
 
 @dataclass
@@ -212,6 +212,16 @@ class AMG:
     # -- setup (reference: amgcl/amg.hpp:467-512 do_init) -------------------
 
     def _build(self, A: CSR):
+        """The whole build, under the ``setup/hierarchy`` span; its
+        ``path`` attribute says which set-up ran: ``device`` (every
+        level built on the device, ops/stencil_device.py), ``hybrid``
+        (a device-built prefix, then the host loop) or ``host``."""
+        with span("setup/hierarchy") as sp:
+            self._build_levels(A)
+            sp.set(path="host" if not self._device_built
+                   else "hybrid" if self._dev_prefix else "device")
+
+    def _build_levels(self, A: CSR):
         prm = self.prm
         self._device_built = False
         self._dev_prefix = []
@@ -223,10 +233,10 @@ class AMG:
         self._format_decisions = None
         self._reorder = None
         # setup-phase profiler (PR 1 instrumented the SOLVE phase only):
-        # device-synced tic/toc scopes + amgcl/setup/* host annotations
-        # around coarsening / galerkin / device transfer / smoother
-        # setup, exported through hierarchy_stats()["setup"] and the
-        # resource ledger
+        # device-synced tic/toc scopes + setup/* spans around
+        # coarsening / galerkin / device transfer / smoother setup, on
+        # both set-up paths, exported through hierarchy_stats()["setup"]
+        # and the resource ledger
         from amgcl_tpu.utils.profiler import Profiler
         prof = self.setup_profile = Profiler.device()
         self._setup_t0 = time.perf_counter()
@@ -239,8 +249,7 @@ class AMG:
             # (ops/stencil_device.py); None -> host path, same numerics
             from amgcl_tpu.ops import stencil_device as sdev
             if sdev.enabled():
-                with setup_scope(prof, "device_build"):
-                    got = sdev.device_build(A, prm)
+                got = sdev.device_build(A, prm, prof)
                 if got is not None:
                     self._device_built = True
                     meta_rows = [(m_, None, None) for m_ in got["meta"]]

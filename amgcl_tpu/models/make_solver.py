@@ -23,6 +23,7 @@ from amgcl_tpu.models.amg import AMG, AMGParams
 from amgcl_tpu.solver.cg import CG
 from amgcl_tpu.telemetry import SolveReport, phase, emit as telemetry_emit
 from amgcl_tpu.telemetry import compile_watch as _cwatch
+from amgcl_tpu.telemetry.tracing import solve_span, span
 
 #: compile-watch label of the fused solve program (one jit cache per
 #: make_solver instance; the watch aggregates them under this name)
@@ -45,159 +46,167 @@ class make_solver:
                  solver_dtype=None, matrix_format: str = "auto",
                  refine: int = 0, refine_dtype: str = "auto",
                  batch: Any = None, recovery: Any = None):
-        # ``recovery``: the fault-tolerance ladder (faults/recovery.py).
-        # None = follow AMGCL_TPU_RECOVERY (off unless "1"); True =
-        # policy from env (checkpoint cadence via AMGCL_TPU_CKPT_EVERY);
-        # False = off; a RecoveryPolicy instance is used as-is.
-        self.recovery = recovery
-        # ``batch``: declared multi-RHS bucket size (serve/): ``__call__``
-        # accepts a stacked (n, B) rhs regardless; the declared value is
-        # the default bucket a SolverService built on this bundle uses
-        self.batch = int(batch) if batch else None
-        if not isinstance(A, CSR):
-            A = CSR.from_scipy(A)
-        self.A_host = A
-        precond = precond if precond is not None else AMGParams()
-        built_from_A = False
-        if isinstance(precond, AMGParams):
-            self.precond = AMG(A, precond)
-            self.precond_dtype = precond.dtype
-            built_from_A = True
-        elif hasattr(precond, "hierarchy"):
-            # prebuilt preconditioner (AMG, AsPreconditioner, Dummy, ...)
-            self.precond = precond
-            self.precond_dtype = getattr(precond, "dtype", None) \
-                or precond.prm.dtype
-        else:
-            raise TypeError(
-                "precond must be AMGParams or an object with .hierarchy, "
-                "got %r" % type(precond))
-        # executed-reorder threading (ISSUE 20): when the hierarchy was
-        # built in a permuted frame (AMG._build applied the structure
-        # advisor's plan), every solver-side device operator must live
-        # in the SAME frame — rhs/x0 are permuted in and x un-permuted
-        # out per solve (_solve_once), so callers never see the layout.
-        self._reorder = plan = getattr(self.precond, "_reorder", None)
-        self._perm_dev = None
-        Ah = A
-        if plan is not None:
-            hl0 = self.precond.host_levels[0][0]
-            if built_from_A:
-                Ah = hl0       # the permuted fine operator, as built
+        with span("setup/make_solver"):
+            # ``recovery``: the fault-tolerance ladder (faults/recovery.py).
+            # None = follow AMGCL_TPU_RECOVERY (off unless "1"); True =
+            # policy from env (checkpoint cadence via AMGCL_TPU_CKPT_EVERY);
+            # False = off; a RecoveryPolicy instance is used as-is.
+            self.recovery = recovery
+            # ``batch``: declared multi-RHS bucket size (serve/): ``__call__``
+            # accepts a stacked (n, B) rhs regardless; the declared value is
+            # the default bucket a SolverService built on this bundle uses
+            self.batch = int(batch) if batch else None
+            if not isinstance(A, CSR):
+                A = CSR.from_scipy(A)
+            self.A_host = A
+            precond = precond if precond is not None else AMGParams()
+            built_from_A = False
+            if isinstance(precond, AMGParams):
+                self.precond = AMG(A, precond)
+                self.precond_dtype = precond.dtype
+                built_from_A = True
+            elif hasattr(precond, "hierarchy"):
+                # prebuilt preconditioner (AMG, AsPreconditioner, Dummy, ...)
+                self.precond = precond
+                self.precond_dtype = getattr(precond, "dtype", None) \
+                    or precond.prm.dtype
             else:
-                from amgcl_tpu.telemetry import structure as _st
-                if _st.fingerprint(A) != plan["fingerprint"]:
-                    raise ValueError(
-                        "prebuilt preconditioner was reordered for a "
-                        "different sparsity pattern than the system "
-                        "matrix; rebuild the preconditioner from this "
-                        "matrix or set AMGCL_TPU_REORDER=off")
-                Ah = CSR(hl0.ptr, hl0.col,
-                         np.asarray(A.val)[plan["val_perm"]], A.ncols)
-        self.solver = solver or CG()
-        self.solver_dtype = solver_dtype or self.precond_dtype
-        self.refine = int(refine)
-        self.matrix_format = matrix_format
-        self._built_from_A = built_from_A
-        hier_A = getattr(getattr(self.precond, "hierarchy", None),
-                         "system_matrix", None)
-        if (built_from_A and hier_A is not None
-                and self.solver_dtype == self.precond_dtype
-                and matrix_format == "auto"):
-            # the hierarchy's finest-level operator IS this matrix in the
-            # same format/dtype — skip a duplicate device conversion.
-            # (Only when the preconditioner was built from A right here — a
-            # prebuilt preconditioner may wrap a different operator.)
-            self.A_dev = hier_A
-        else:
-            # share the hierarchy's dense-window HBM budget when there is
-            # one — the Krylov-side copy draws from the same pool as the
-            # level operators instead of claiming a fresh allowance
-            self.A_dev = dev.to_device(
-                Ah, matrix_format, self.solver_dtype,
-                budget=getattr(self.precond, "_dwin_budget", None))
-        # refinement needs the outer residual b - A x evaluated more
-        # accurately than the working precision (the f32 evaluation
-        # floors around eps32·||A||·||x||/||b||, far above 1e-6 for
-        # large stiff systems). Two routes:
-        #   'float64' — the wide operator (reference spirit; on TPU the
-        #               f64 pass runs in software emulation);
-        #   'df32'    — compensated two-f32 arithmetic (ops/dfloat.py):
-        #               the same accuracy class at f32 hardware speed,
-        #               DIA operators only; the f32 rhs is treated as
-        #               exact (b_lo = 0).
-        # 'auto' picks df32 on TPU for real-f32 DIA systems, float64
-        # elsewhere.
-        self.A_dev64 = None
-        self.refine_mode = None
-        # True when the df32 self-check failed and refinement fell back
-        # to float64
-        self.refine_fallback = False
-        if self.refine > 0:
-            import jax as _jax
-            if refine_dtype == "auto":
-                use_df = (_jax.default_backend() == "tpu"
-                          and isinstance(self.A_dev, dev.DiaMatrix)
-                          and jnp.dtype(self.solver_dtype)
-                          == jnp.dtype(jnp.float32))
-                refine_dtype = "df32" if use_df else "float64"
-            if refine_dtype == "df32":
-                # the lo operator is the f32 rounding remainder and the
-                # Dekker splitter is f32-specific — the hi half must be
-                # exactly float32
-                if not isinstance(self.A_dev, dev.DiaMatrix) \
-                        or jnp.dtype(self.solver_dtype) \
-                        != jnp.dtype(jnp.float32):
-                    raise ValueError(
-                        "refine_dtype='df32' needs a float32 DIA system "
-                        "matrix; use refine_dtype='float64'")
-                self.refine_mode = "df32"
-                self.A_dev64 = self._build_lo_operator(Ah)
-                if not self._df32_selfcheck(Ah):
-                    # error-free transforms assume every f32 op rounds
-                    # once — a backend compiling them with excess
-                    # precision or reassociation silently degrades the
-                    # compensated residual to the plain-f32 floor; ONE
-                    # on-device check against a host f64 reference
-                    # catches that class before it becomes a
-                    # convergence mystery
-                    import warnings
-                    warnings.warn(
-                        "df32 compensated residual failed its on-device "
-                        "accuracy self-check; falling back to "
-                        "refine_dtype='float64'")
-                    if not _jax.config.jax_enable_x64:
+                raise TypeError(
+                    "precond must be AMGParams or an object with .hierarchy, "
+                    "got %r" % type(precond))
+            # executed-reorder threading: when the hierarchy was
+            # built in a permuted frame (AMG._build applied the structure
+            # advisor's plan), every solver-side device operator must live
+            # in the SAME frame — rhs/x0 are permuted in and x un-permuted
+            # out per solve (_solve_once), so callers never see the layout.
+            self._reorder = plan = getattr(self.precond, "_reorder", None)
+            self._perm_dev = None
+            Ah = A
+            if plan is not None:
+                hl0 = self.precond.host_levels[0][0]
+                if built_from_A:
+                    Ah = hl0       # the permuted fine operator, as built
+                else:
+                    from amgcl_tpu.telemetry import structure as _st
+                    if _st.fingerprint(A) != plan["fingerprint"]:
+                        raise ValueError(
+                            "prebuilt preconditioner was reordered for a "
+                            "different sparsity pattern than the system "
+                            "matrix; rebuild the preconditioner from this "
+                            "matrix or set AMGCL_TPU_REORDER=off")
+                    Ah = CSR(hl0.ptr, hl0.col,
+                             np.asarray(A.val)[plan["val_perm"]], A.ncols)
+            self.solver = solver or CG()
+            self.solver_dtype = solver_dtype or self.precond_dtype
+            self.refine = int(refine)
+            self.matrix_format = matrix_format
+            self._built_from_A = built_from_A
+            hier_A = getattr(getattr(self.precond, "hierarchy", None),
+                             "system_matrix", None)
+            if (built_from_A and hier_A is not None
+                    and self.solver_dtype == self.precond_dtype
+                    and matrix_format == "auto"):
+                # the hierarchy's finest-level operator IS this matrix in
+                # the same format/dtype — skip a duplicate device
+                # conversion. (Only when the preconditioner was built from
+                # A right here — a prebuilt preconditioner may wrap a
+                # different operator.)
+                self.A_dev = hier_A
+            else:
+                # share the hierarchy's dense-window HBM budget when there
+                # is one — the Krylov-side copy draws from the same pool as
+                # the level operators instead of claiming a fresh allowance
+                with span("setup/system_operator"):
+                    self.A_dev = dev.to_device(
+                        Ah, matrix_format, self.solver_dtype,
+                        budget=getattr(self.precond, "_dwin_budget", None))
+            # refinement needs the outer residual b - A x evaluated more
+            # accurately than the working precision (the f32 evaluation
+            # floors around eps32·||A||·||x||/||b||, far above 1e-6 for
+            # large stiff systems). Two routes:
+            #   'float64' — the wide operator (reference spirit; on TPU the
+            #               f64 pass runs in software emulation);
+            #   'df32'    — compensated two-f32 arithmetic (ops/dfloat.py):
+            #               the same accuracy class at f32 hardware speed,
+            #               DIA operators only; the f32 rhs is treated as
+            #               exact (b_lo = 0).
+            # 'auto' picks df32 on TPU for real-f32 DIA systems, float64
+            # elsewhere.
+            self.A_dev64 = None
+            self.refine_mode = None
+            # True when the df32 self-check failed and refinement fell back
+            # to float64
+            self.refine_fallback = False
+            if self.refine > 0:
+                import jax as _jax
+                if refine_dtype == "auto":
+                    use_df = (_jax.default_backend() == "tpu"
+                              and isinstance(self.A_dev, dev.DiaMatrix)
+                              and jnp.dtype(self.solver_dtype)
+                              == jnp.dtype(jnp.float32))
+                    refine_dtype = "df32" if use_df else "float64"
+                if refine_dtype == "df32":
+                    # the lo operator is the f32 rounding remainder and the
+                    # Dekker splitter is f32-specific — the hi half must be
+                    # exactly float32
+                    if not isinstance(self.A_dev, dev.DiaMatrix) \
+                            or jnp.dtype(self.solver_dtype) \
+                            != jnp.dtype(jnp.float32):
+                        raise ValueError(
+                            "refine_dtype='df32' needs a float32 DIA system "
+                            "matrix; use refine_dtype='float64'")
+                    self.refine_mode = "df32"
+                    with span("setup/system_operator"):
+                        self.A_dev64 = self._build_lo_operator(Ah)
+                    with span("setup/df32_selfcheck"):
+                        df32_sound = self._df32_selfcheck(Ah)
+                    if not df32_sound:
+                        # error-free transforms assume every f32 op rounds
+                        # once — a backend compiling them with excess
+                        # precision or reassociation silently degrades the
+                        # compensated residual to the plain-f32 floor; ONE
+                        # on-device check against a host f64 reference
+                        # catches that class before it becomes a
+                        # convergence mystery
+                        import warnings
                         warnings.warn(
-                            "refine>0 with refine_dtype='float64' "
-                            "requires jax_enable_x64; without it the "
-                            "float64 residual silently truncates to "
-                            "float32 and refinement gains nothing")
+                            "df32 compensated residual failed its on-device "
+                            "accuracy self-check; falling back to "
+                            "refine_dtype='float64'")
+                        if not _jax.config.jax_enable_x64:
+                            warnings.warn(
+                                "refine>0 with refine_dtype='float64' "
+                                "requires jax_enable_x64; without it the "
+                                "float64 residual silently truncates to "
+                                "float32 and refinement gains nothing")
+                        self.refine_mode = "float64"
+                        self.refine_fallback = True
+                        with span("setup/system_operator"):
+                            self.A_dev64 = dev.to_device(
+                                Ah, matrix_format, self._wide_dtype())
+                else:
+                    if not _jax.config.jax_enable_x64:
+                        import warnings
+                        warnings.warn(
+                            "refine>0 with refine_dtype='float64' requires "
+                            "jax_enable_x64; without it the float64 "
+                            "residual silently truncates to float32 and "
+                            "refinement gains nothing — enable x64, drop "
+                            "refine, or use refine_dtype='df32'")
                     self.refine_mode = "float64"
-                    self.refine_fallback = True
-                    self.A_dev64 = dev.to_device(Ah, matrix_format,
-                                                 self._wide_dtype())
-            else:
-                if not _jax.config.jax_enable_x64:
-                    import warnings
-                    warnings.warn(
-                        "refine>0 with refine_dtype='float64' requires "
-                        "jax_enable_x64; without it the float64 residual "
-                        "silently truncates to float32 and refinement "
-                        "gains nothing — enable x64, drop refine, or use "
-                        "refine_dtype='df32'")
-                self.refine_mode = "float64"
-                self.A_dev64 = dev.to_device(Ah, matrix_format,
-                                             self._wide_dtype())
-        self._compiled = None
-        try:
-            # measured-memory attribution (telemetry/memwatch.py): the
-            # Krylov-side system operator(s) get their own owner row,
-            # separate from the hierarchy the AMG registers itself
-            from amgcl_tpu.telemetry import memwatch as _mw
-            if _mw.enabled():
-                _mw.register_owner("operator", self)
-        except Exception:
-            pass
+                    with span("setup/system_operator"):
+                        self.A_dev64 = dev.to_device(Ah, matrix_format,
+                                                     self._wide_dtype())
+            self._compiled = None
+            try:
+                # measured-memory attribution (telemetry/memwatch.py): the
+                # Krylov-side system operator(s) get their own owner row,
+                # separate from the hierarchy the AMG registers itself
+                from amgcl_tpu.telemetry import memwatch as _mw
+                if _mw.enabled():
+                    _mw.register_owner("operator", self)
+            except Exception:
+                pass
 
     def _build_lo_operator(self, A):
         """DIA matrix of the f32 rounding remainders: A ≈ A_hi + A_lo
@@ -515,8 +524,43 @@ class make_solver:
         return solve_with_recovery(self, rhs, x0, policy)
 
     def _solve_once(self, rhs, x0=None):
-        n = self.A_host.nrows * self.A_host.block_size[0]
+        """One dispatch of the solve program, under the ``solve`` span
+        (telemetry/tracing.py) and its steps: ``solve/prepare``,
+        ``solve/dispatch``, ``solve/fetch`` (the host waits on the
+        device) and ``solve/report``."""
         shp = np.shape(rhs)
+        first_call = self._compiled is None
+        with solve_span(first_call=first_call,
+                        batched=len(shp) == 2) as sp:
+            sp.step("solve/prepare")
+            rhs, x0, rhs_d, x0_d = self._prepare(rhs, x0, shp)
+            t0 = time.perf_counter()
+            if first_call:
+                self._wrapped_solve_fn()
+            entry, nspec = self._dispatch_entry()
+            cw0 = _cwatch.snapshot(_SOLVE_FN) if _cwatch.enabled() \
+                else None
+            sp.step("solve/dispatch")
+            got = self._dispatch(entry, nspec, rhs, x0, rhs_d, x0_d)
+            x = got[0]
+            if getattr(self, "_reorder", None) is not None:
+                _, iperm = self._perm_pair()
+                x = jnp.take(x, iperm, axis=0)   # to the caller's frame
+            sp.step("solve/fetch")
+            # ONE device->host round trip for everything the SolverInfo
+            # needs — separate int()/float()/np.asarray() conversions
+            # would each pay a full device sync (the None slots for
+            # hist/health pass through device_get as empty pytree nodes)
+            fetched = jax.device_get(got[1:6])
+            sp.step("solve/report")
+            report = self._report(fetched, shp, rhs, x0, x, t0,
+                                  first_call, cw0, sp)
+        return x, report
+
+    def _prepare(self, rhs, x0, shp):
+        """Shape checks, device arrays of ``rhs`` and ``x0`` (zeros when
+        None), and their copies in the hierarchy's frame."""
+        n = self.A_host.nrows * self.A_host.block_size[0]
         batched = len(shp) == 2
         if not (shp == (n,) or (batched and shp[0] == n and shp[1] >= 1)):
             raise ValueError(
@@ -537,18 +581,19 @@ class make_solver:
             x0 = jnp.zeros_like(rhs)
         # executed-reorder seam: dispatch in the hierarchy's permuted
         # frame; the ORIGINAL-frame rhs/x0 names stay live for the df32
-        # runtime check and the flight recorder below (both evaluate
-        # against self.A_host, which is original-order). jnp.take with
-        # axis=0 covers the stacked (n, B) case unchanged.
+        # runtime check and the flight recorder (both evaluate against
+        # self.A_host, which is original-order). jnp.take with axis=0
+        # covers the stacked (n, B) case unchanged.
         rhs_d, x0_d = rhs, x0
         if getattr(self, "_reorder", None) is not None:
             perm, _ = self._perm_pair()
             rhs_d = jnp.take(rhs, perm, axis=0)
             x0_d = jnp.take(x0, perm, axis=0)
-        t0 = time.perf_counter()
-        first_call = self._compiled is None
-        if first_call:
-            self._wrapped_solve_fn()
+        return rhs, x0, rhs_d, x0_d
+
+    def _dispatch_entry(self):
+        """The program this call dispatches, and the pending numeric
+        fault spec (None unless a fault plan fired)."""
         # fault seams (faults/inject.py), both one env read when no
         # plan is armed: ``device.loss`` raises the typed error at the
         # dispatch boundary (the recovery ladder resumes from the last
@@ -577,10 +622,12 @@ class make_solver:
             if nspec is not None:
                 entry = _cwatch.watched_jit(self._solve_fn,
                                             name=_SOLVE_FN)
-        cw0 = _cwatch.snapshot(_SOLVE_FN) if _cwatch.enabled() else None
+        return entry, nspec
+
+    def _dispatch(self, entry, nspec, rhs, x0, rhs_d, x0_d):
         try:
-            got = entry(self.A_dev, self.A_dev64,
-                        self.precond.hierarchy, rhs_d, x0_d)
+            return entry(self.A_dev, self.A_dev64,
+                         self.precond.hierarchy, rhs_d, x0_d)
         except Exception as e:
             # OOM seam (ISSUE 18): a backend RESOURCE_EXHAUSTED used to
             # escape as a raw XlaRuntimeError — classify, trip the
@@ -605,15 +652,15 @@ class make_solver:
             if nspec is not None:
                 from amgcl_tpu.faults import inject as _inject
                 _inject.end_numeric_dispatch()
-        x = got[0]
-        if getattr(self, "_reorder", None) is not None:
-            _, iperm = self._perm_pair()
-            x = jnp.take(x, iperm, axis=0)   # back to the caller's frame
-        # ONE device->host round trip for everything the SolverInfo needs —
-        # separate int()/float()/np.asarray() conversions would each pay
-        # a full device sync (the None slots for hist/health pass through
-        # device_get as empty pytree nodes)
-        iters, resid, hist_buf, hist_n, hstate = jax.device_get(got[1:6])
+
+    def _report(self, fetched, shp, rhs, x0, x, t0, first_call, cw0, sp):
+        """The SolveReport of one call from its fetched scalars; each
+        telemetry leg it calls runs between ``sp.begin(
+        "solve/report/<leg>")`` and ``sp.end()`` on the ``solve`` span
+        ``sp``, a child of its ``solve/report`` step."""
+        n = self.A_host.nrows * self.A_host.block_size[0]
+        batched = len(shp) == 2
+        iters, resid, hist_buf, hist_n, hstate = fetched
         hist = None
         per_rhs = None
         if batched:
@@ -640,6 +687,7 @@ class make_solver:
             hist = np.asarray(hist_buf)[:int(hist_n)]
         health = None
         if hstate is not None:
+            sp.begin("solve/report/health")
             from amgcl_tpu.telemetry import health as _health
             if batched:
                 from amgcl_tpu.serve.batched import decode_batched_health
@@ -648,6 +696,7 @@ class make_solver:
                     np.atleast_2d(np.asarray(hstate.first_it)))
             else:
                 health = _health.decode(hstate.flags, hstate.first_it)
+            sp.end()
         wall = time.perf_counter() - t0
         extra = {"first_call": True} if first_call else {}
         if batched:
@@ -659,7 +708,9 @@ class make_solver:
             # it into the refinement loop, where reassociation can undo
             # the compensation. Validate the first compiled call's
             # reported residual against a host f64 residual once.
+            sp.begin("solve/report/df32_check")
             self._check_df32_runtime(rhs, x, float(resid))
+            sp.end()
         if getattr(self, "_df32_drift", None) is not None:
             # set by _check_df32_runtime on harmful drift — sticky so the
             # doctor sees it on every later report from this bundle
@@ -680,8 +731,10 @@ class make_solver:
             # per-call compile delta: 0 new traces on a warm repeat, 1 on
             # a fresh shape — the recompile counter the roofline tests
             # pin down
+            sp.begin("solve/report/compile_watch")
             cw1 = _cwatch.snapshot(_SOLVE_FN)
             delta = _cwatch.delta(cw0, cw1)
+            sp.end()
         tags = getattr(self, "_lowering_tags", None)
         if tags is None:
             tags = self._lowering_tags = {}
@@ -725,32 +778,19 @@ class make_solver:
                         batch=int(shp[1]))
             except Exception:
                 pass
-        try:
-            # whole-solve roofline (telemetry/roofline.py): achieved
-            # GB/s / GFLOP/s of this call from the ledger's per-iteration
-            # model. Updated IN PLACE on the cached resources dict so the
-            # latest call's numbers win (prior reports alias the dict);
-            # the JSONL 'solve' event below snapshots the current value
-            from amgcl_tpu.telemetry import roofline as _roofline
-            pi = resources.get("per_iteration") if resources else None
-            if pi is not None:
-                rf = _roofline.solve_roofline(pi, int(iters), wall,
-                                              first_call=first_call)
-                if rf is not None:
-                    resources["roofline"] = rf
-        except Exception:
-            pass                 # roofline must never fail a solve
-        try:
+        from amgcl_tpu.telemetry import memwatch as _mw
+        if resources is not None and _mw.enabled():
             # measured memory join (telemetry/memwatch.py): what the
             # device ACTUALLY holds for this bundle, with provenance —
-            # in place on the cached dict, same contract as roofline
-            from amgcl_tpu.telemetry import memwatch as _mw
-            if resources is not None and _mw.enabled():
+            # in place on the cached dict (prior reports alias it)
+            sp.begin("solve/report/memwatch")
+            try:
                 bm = _mw.solve_resources(self)
                 if bm is not None:
                     resources["bytes_measured"] = bm
-        except Exception:
-            pass                 # measurement must never fail a solve
+            except Exception:
+                pass         # measurement must never fail a solve
+            sp.end()
         report = SolveReport(
             int(iters), float(resid), hist, wall_time_s=wall,
             solves_per_sec=round(shp[1] / wall, 3)
@@ -768,32 +808,36 @@ class make_solver:
         # bundle) and, on a FATAL guard trip, dump a self-contained
         # replay bundle so the field incident becomes a deterministic
         # repro. Best-effort: the recorder must never fail a solve.
-        try:
-            from amgcl_tpu.telemetry import flight as _flight
-            if _flight.enabled():
+        from amgcl_tpu.telemetry import flight as _flight
+        if _flight.enabled():
+            sp.begin("solve/report/flight")
+            try:
                 _flight.record_solve(self, rhs, x0, report)
                 if _flight.fatal_health(health):
                     _flight.dump("health_trip", bundle=self, rhs=rhs,
                                  x0=x0, report=report,
                                  tags={"flags": health.get("flags")})
-        except Exception:
-            pass
+            except Exception:
+                pass
+            sp.end()
         # process-global JSONL sink (telemetry/sink.py); the NullSink check
         # keeps the unconfigured hot path free of the to_dict() conversion
         # (this function already fights per-call host overhead — see the
-        # single-fetch comment above)
+        # single-fetch comment in _solve_once)
         from amgcl_tpu.telemetry.sink import NullSink, get_default_sink
         if not isinstance(get_default_sink(), NullSink):
+            sp.begin("solve/report/sink")
             telemetry_emit(report.to_dict(), event="solve", n=n)
             if health is not None and not health["ok"]:
-                # a dedicated, easily-grepped event for every unhealthy
-                # solve — the decoded guard record plus the numbers a
-                # dashboard alert needs
+                # a dedicated, easily-grepped event for every
+                # unhealthy solve — the decoded guard record plus
+                # the numbers a dashboard alert needs
                 telemetry_emit(event="health", n=n,
                                solver=type(self.solver).__name__,
                                iters=int(iters), resid=float(resid),
                                **health)
-        return x, report
+            sp.end()
+        return report
 
     def _wrapped_solve_fn(self):
         """THE jit wrap of the solve program — observed jit

@@ -43,8 +43,6 @@ from __future__ import annotations
 
 import functools
 import os
-import sys
-import time
 
 import numpy as np
 import jax
@@ -54,15 +52,9 @@ from jax import lax
 
 from amgcl_tpu.ops.csr import CSR
 from amgcl_tpu.ops.stencil import HostDia, host_dia_from_csr, _flat
+from amgcl_tpu.telemetry.tracing import setup_scope
 
 _MAX_DIAGS = 34          # per-level gate; pair scans stay ~10^3 steps
-
-# Per-phase wall breakdown of the most recent profiled device setup
-# (AMGCL_TPU_PROFILE_SETUP=1): list of (tag, seconds). bench.py re-runs
-# setup with profiling on and embeds this in the artifact so a chip run
-# can tell device programs from host round trips from probe compiles
-# without scraping stderr.
-LAST_SETUP_PROFILE: list = []
 
 
 def enabled() -> bool:
@@ -379,7 +371,7 @@ class _LevelMeta:
         self.shape = (self.nrows, self.nrows)
 
 
-def device_build(A: CSR, prm):
+def device_build(A: CSR, prm, prof=None):
     """Build the SA hierarchy on device — as far as the diagonal-pair
     Galerkin stays cheap (coarse SA stencils grow to ~125 diagonals by
     level 2, where the CSR SpGEMM route wins). Returns None when the
@@ -394,7 +386,16 @@ def device_build(A: CSR, prm):
     - ``eps_next``: eps_strong after the per-level decay, for the
       continuation's build context.
 
-    Numerics are identical to the host path either way."""
+    Numerics are identical to the host path either way.
+
+    The stages run under the host loop's set-up stage names
+    (``setup_scope`` on ``prof``, the build's profiler): the fine
+    operator's DIA packing and upload and each level's DIA operators are
+    ``level<i>/transfer``; the ``_level_setup`` program (strength,
+    aggregation, smoother state and Galerkin in one device program) and
+    its counts fetch are ``level<i>/galerkin``; the fused-leg builds are
+    ``level<i>/fused_kernels``; the coarsest operator, its fetch and the
+    direct factorization are ``coarse_solver``."""
     from amgcl_tpu.coarsening.smoothed_aggregation import \
         SmoothedAggregation
     from amgcl_tpu.relaxation.spai0 import Spai0
@@ -430,43 +431,26 @@ def device_build(A: CSR, prm):
     grid = detect_grid_csr(A)
     if grid is None:
         return None
-    Ad = host_dia_from_csr(A, grid, np.float32)
-    if Ad is None or len(Ad.offsets3) > _MAX_DIAGS:
-        return None
+    with setup_scope(prof, "level0/transfer"):
+        Ad = host_dia_from_csr(A, grid, np.float32)
+        if Ad is None or len(Ad.offsets3) > _MAX_DIAGS:
+            return None
+        adata = jnp.asarray(Ad.data)
 
     dtype = prm.dtype
     offs = list(Ad.offsets3)
     dims = tuple(Ad.dims)
-    adata = jnp.asarray(Ad.data)
     eps = float(c.eps_strong)
     n = int(np.prod(dims))
     meta = [_LevelMeta(n, A.nnz)]
     dev_levels = []
 
-    # AMGCL_TPU_PROFILE_SETUP=1: per-phase wall breakdown to stderr, to
-    # tell device programs from host round trips from fused-kernel probe
-    # compiles
-    _prof_on = os.environ.get("AMGCL_TPU_PROFILE_SETUP") == "1"
-    _prof_t = [time.perf_counter()]
-    if _prof_on:
-        LAST_SETUP_PROFILE.clear()
-
-    def _mark(tag, *block_on):
-        if not _prof_on:
-            return
-        for a in block_on:
-            jax.block_until_ready(a)
-        now = time.perf_counter()
-        LAST_SETUP_PROFILE.append((tag, round(now - _prof_t[0], 4)))
-        print("[setup-prof] %-28s %7.3f s" % (tag, now - _prof_t[0]),
-              file=sys.stderr)
-        _prof_t[0] = now
-
     def leftover_csr():
         """Download the current level and hand it to the host loop with
         its DIA packing and grid dims attached (transfer-only re-use)."""
-        Hl = HostDia(offs, np.asarray(jax.device_get(adata)), dims)
-        return Hl.to_csr()
+        with setup_scope(prof, "level%d/transfer" % len(dev_levels)):
+            Hl = HostDia(offs, np.asarray(jax.device_get(adata)), dims)
+            return Hl.to_csr()
 
     def result(leftover, coarse_solver):
         return {"levels": dev_levels, "meta": meta, "leftover": leftover,
@@ -484,61 +468,62 @@ def device_build(A: CSR, prm):
         if all(b == 1 for b in blocks):
             return None if not dev_levels \
                 else result(leftover_csr(), None)
-        coarse = tuple(-(-d // b) for d, b in zip(dims, blocks))
-        m, mt, ac_all, scale, counts, axis_strong = _level_setup(
-            adata, jnp.float32(eps), jnp.float32(c.relax),
-            jnp.float32(sm_omega), offs=tuple(offs), dims=dims,
-            blocks=blocks, coarse=coarse, relax_kind=relax_kind)
-        _mark("level_setup n=%d" % n, m, ac_all)
-        counts_h, axis_h = jax.device_get((counts, axis_strong))
-        _mark("fetch counts/axes")
-        # speculation check (ops/stencil.strength_axes semantics): every
-        # extent>1 axis must actually be strongly coupled. A mismatch is a
-        # SEMICOARSENING problem: rerun the level with the measured axes
-        # (one extra compile per (dims, blocks) shape — cached across
-        # rebuilds); no strong axis at all means aggregation would stall,
-        # so that still falls back to the host MIS route.
-        want = tuple(
-            min(2, dims[i]) if dims[i] > 1 and axis_h[i] >= 0.5 * n else 1
-            for i in range(3))
-        if want != blocks:
-            if all(b == 1 for b in want):
-                return None if not dev_levels \
-                    else result(leftover_csr(), None)
-            blocks = want
+        lvl = "level%d" % len(dev_levels)
+        with setup_scope(prof, lvl + "/galerkin"):
             coarse = tuple(-(-d // b) for d, b in zip(dims, blocks))
             m, mt, ac_all, scale, counts, axis_strong = _level_setup(
                 adata, jnp.float32(eps), jnp.float32(c.relax),
                 jnp.float32(sm_omega), offs=tuple(offs), dims=dims,
                 blocks=blocks, coarse=coarse, relax_kind=relax_kind)
-            counts_h = jax.device_get(counts)
+            counts_h, axis_h = jax.device_get((counts, axis_strong))
+            # speculation check (ops/stencil.strength_axes semantics):
+            # every extent>1 axis must actually be strongly coupled. A
+            # mismatch is a SEMICOARSENING problem: rerun the level with
+            # the measured axes (one extra compile per (dims, blocks)
+            # shape — cached across rebuilds); no strong axis at all
+            # means aggregation would stall, so that still falls back to
+            # the host MIS route.
+            want = tuple(
+                min(2, dims[i]) if dims[i] > 1 and axis_h[i] >= 0.5 * n
+                else 1 for i in range(3))
+            if want != blocks:
+                if all(b == 1 for b in want):
+                    return None if not dev_levels \
+                        else result(leftover_csr(), None)
+                blocks = want
+                coarse = tuple(-(-d // b) for d, b in zip(dims, blocks))
+                m, mt, ac_all, scale, counts, axis_strong = _level_setup(
+                    adata, jnp.float32(eps), jnp.float32(c.relax),
+                    jnp.float32(sm_omega), offs=tuple(offs), dims=dims,
+                    blocks=blocks, coarse=coarse, relax_kind=relax_kind)
+                counts_h = jax.device_get(counts)
 
-        main_in = (0, 0, 0) in offs
-        af_offs = list(offs) + ([] if main_in else [(0, 0, 0)])
-        mt_offs = [_oneg(o) for o in af_offs]
-        s_offs, _, _ = _product_plan(
-            mt_offs, _product_plan(offs, af_offs, dims)[0], dims)
-        c_offs, _, _ = _collapse_plan(s_offs, dims, blocks, coarse)
-        keep = np.flatnonzero(counts_h)
-        if len(keep) == 0:
-            return None
-        new_offs = [c_offs[k] for k in keep]
-        ac = ac_all[jnp.asarray(keep)]
+            main_in = (0, 0, 0) in offs
+            af_offs = list(offs) + ([] if main_in else [(0, 0, 0)])
+            mt_offs = [_oneg(o) for o in af_offs]
+            s_offs, _, _ = _product_plan(
+                mt_offs, _product_plan(offs, af_offs, dims)[0], dims)
+            c_offs, _, _ = _collapse_plan(s_offs, dims, blocks, coarse)
+            keep = np.flatnonzero(counts_h)
+            if len(keep) == 0:
+                return None
+            new_offs = [c_offs[k] for k in keep]
+            ac = ac_all[jnp.asarray(keep)]
 
-        T = GridTentative(dims, blocks, coarse)
-        M_dev = _to_dia_matrix(m, af_offs, dims, dtype)
-        Mt_dev = _to_dia_matrix(mt, mt_offs, dims, dtype)
+        with setup_scope(prof, lvl + "/transfer"):
+            T = GridTentative(dims, blocks, coarse)
+            M_dev = _to_dia_matrix(m, af_offs, dims, dtype)
+            Mt_dev = _to_dia_matrix(mt, mt_offs, dims, dtype)
+            A_lvl = _to_dia_matrix(adata, offs, dims, dtype)
+            R_lvl = ImplicitSmoothedR(T, Mt_dev)
+            P_lvl = ImplicitSmoothedP(T, M_dev)
+            relax_lvl = ScaledResidualSmoother(
+                scale.astype(jnp.dtype(dtype)))
         from amgcl_tpu.ops.pallas_vcycle import (build_fused_down,
                                                  build_fused_up)
-        A_lvl = _to_dia_matrix(adata, offs, dims, dtype)
-        _mark("to_dia x3", A_lvl.data, M_dev.data, Mt_dev.data)
-        R_lvl = ImplicitSmoothedR(T, Mt_dev)
-        P_lvl = ImplicitSmoothedP(T, M_dev)
-        relax_lvl = ScaledResidualSmoother(scale.astype(jnp.dtype(dtype)))
-        fd = build_fused_down(A_lvl, R_lvl, relax_lvl)
-        _mark("fused_down build")
-        fu = build_fused_up(A_lvl, P_lvl, relax_lvl)
-        _mark("fused_up build")
+        with setup_scope(prof, lvl + "/fused_kernels"):
+            fd = build_fused_down(A_lvl, R_lvl, relax_lvl)
+            fu = build_fused_up(A_lvl, P_lvl, relax_lvl)
         dev_levels.append(Level(A_lvl, relax_lvl, P_lvl, R_lvl, fd, fu))
 
         adata, offs, dims = ac, new_offs, coarse
@@ -556,26 +541,25 @@ def device_build(A: CSR, prm):
             "cannot build a dense coarse solver this large — adjust "
             "coarsening parameters or set direct_coarse=False"
             % (n, prm.coarse_enough))
-    A_last = _to_dia_matrix(adata, offs, dims, dtype)
-    if prm.direct_coarse:
-        Hl = HostDia(offs, np.asarray(jax.device_get(adata), np.float64),
-                     dims)
-        _mark("coarse fetch")
-        coarse_solver = DenseDirectSolver.build(Hl.to_csr(), dtype)
-        _mark("coarse direct build")
-        dev_levels.append(Level(A_last, None))
-    else:
-        coarse_solver = None
-        dl = jax.device_get(adata)
-        main_k = offs.index((0, 0, 0)) if (0, 0, 0) in offs else None
-        d0 = dl[main_k] if main_k is not None else np.ones(n)
-        if relax_kind == "spai0":
-            denom = (dl * dl).sum(axis=0)
-            sc = d0 / np.where(denom != 0, denom, 1)
+    with setup_scope(prof, "coarse_solver"):
+        A_last = _to_dia_matrix(adata, offs, dims, dtype)
+        if prm.direct_coarse:
+            Hl = HostDia(offs, np.asarray(jax.device_get(adata),
+                                          np.float64), dims)
+            coarse_solver = DenseDirectSolver.build(Hl.to_csr(), dtype)
+            dev_levels.append(Level(A_last, None))
         else:
-            sc = sm_omega * np.where(d0 != 0, 1.0 / np.where(
-                d0 != 0, d0, 1), 0.0)
-        dev_levels.append(Level(
-            A_last,
-            ScaledResidualSmoother(jnp.asarray(sc, dtype=dtype))))
+            coarse_solver = None
+            dl = jax.device_get(adata)
+            main_k = offs.index((0, 0, 0)) if (0, 0, 0) in offs else None
+            d0 = dl[main_k] if main_k is not None else np.ones(n)
+            if relax_kind == "spai0":
+                denom = (dl * dl).sum(axis=0)
+                sc = d0 / np.where(denom != 0, denom, 1)
+            else:
+                sc = sm_omega * np.where(d0 != 0, 1.0 / np.where(
+                    d0 != 0, d0, 1), 0.0)
+            dev_levels.append(Level(
+                A_last,
+                ScaledResidualSmoother(jnp.asarray(sc, dtype=dtype))))
     return result(None, coarse_solver)

@@ -39,7 +39,7 @@ plus the efficiency leg (PR 4):
 from amgcl_tpu.telemetry.report import SolveReport
 from amgcl_tpu.telemetry.history import HistoryMixin
 from amgcl_tpu.telemetry.tracing import (phase, annotate, setup_scope,
-                                         RequestSpans)
+                                         span, RequestSpans)
 from amgcl_tpu.telemetry.sink import (JsonlSink, NullSink, emit,
                                       get_default_sink, set_default_sink)
 from amgcl_tpu.telemetry.health import (HealthState, decode as decode_health,
@@ -82,7 +82,7 @@ from amgcl_tpu.telemetry import structure
 from amgcl_tpu.telemetry import memwatch
 
 __all__ = ["SolveReport", "HistoryMixin", "phase", "annotate",
-           "setup_scope", "RequestSpans", "JsonlSink", "NullSink",
+           "setup_scope", "span", "RequestSpans", "JsonlSink", "NullSink",
            "emit",
            "get_default_sink", "set_default_sink", "DeviceMemoryBudget",
            "dense_window_budget", "hierarchy_ledger", "summarize_ledger",

@@ -133,6 +133,10 @@ class CompileWatch:
         # so concurrent solves on different threads cannot cross-book
         self._tls = threading.local()
         self._installed = False
+        #: process-wide [jaxpr traces, trace seconds, backend compiles,
+        #: compile seconds], every function and thread — what a set-up
+        #: span reads on entry and exit (telemetry/tracing.py)
+        self._counters = [0, 0.0, 0, 0.0]
 
     @property
     def _stack(self) -> List[str]:
@@ -185,8 +189,15 @@ class CompileWatch:
         return self
 
     def _on_duration(self, event: str, duration: float, **kw) -> None:
-        # '/jax/core/compile/backend_compile_duration' et al.; everything
-        # else on the channel is ignored
+        # '/jax/core/compile/backend_compile_duration' (a compile, or the
+        # load of a program found in the persistent cache) and
+        # '/jax/core/compile/jaxpr_trace_duration'; everything else on
+        # the channel is ignored
+        if event.endswith("jaxpr_trace_duration"):
+            with _LOCK:
+                self._counters[0] += 1
+                self._counters[1] += float(duration)
+            return
         if "backend_compile" not in event:
             return
         cur = self._stack[-1] if self._stack else UNWATCHED
@@ -194,6 +205,14 @@ class CompileWatch:
             rec = self._fn(cur)
             rec["backend_compiles"] += 1
             rec["compile_s"] += float(duration)
+            self._counters[2] += 1
+            self._counters[3] += float(duration)
+
+    def counters(self):
+        """(jaxpr traces, trace seconds, backend compiles, compile
+        seconds) observed so far in this process."""
+        with _LOCK:
+            return tuple(self._counters)
 
     # -- export --------------------------------------------------------------
 

@@ -29,8 +29,8 @@ the device's peaks:
   (DIA on CPU XLA) legitimately report more bytes accessed; dense and
   scaled-residual stages agree to ~1%.
 * :func:`solve_roofline` — the per-Krylov-iteration variant from one
-  solve's wall time and the ledger's iteration model
-  (``SolveReport.resources["roofline"]``). The iteration model prices
+  solve's wall time and the ledger's iteration model (``bench.py``
+  calls it; solves no longer carry it). The iteration model prices
   the fused tiers at their single-stream cost (fused V-cycle legs via
   ``cycle_cost_model``'s ``down_fused``/``up_fused`` rows, fused vector
   algebra via ``KRYLOV_VEC_STREAMS_FUSED``) — no double counting of
@@ -530,8 +530,8 @@ def solve_roofline(per_iteration: Dict[str, Any], iters: int,
                    peaks: Optional[Dict[str, Any]] = None,
                    first_call: bool = False) -> Optional[Dict[str, Any]]:
     """Whole-solve roofline from the ledger's per-Krylov-iteration model
-    and one solve's wall time — the cheap, measurement-free variant that
-    rides every ``SolveReport.resources``. Wall time includes dispatch
+    and one solve's wall time — the cheap, measurement-free variant.
+    Wall time includes dispatch
     and fetch overhead (and compile, when ``first_call`` — flagged), so
     this is a lower bound on the achieved rate."""
     flops = per_iteration.get("flops")

@@ -971,28 +971,21 @@ def main_worker():
             levels = [{"error": repr(e)}]   # headline number
         _PARTIAL["levels"] = levels
     if _enough("setup_profile", 120):
-        # warm-cache setup re-run with per-phase blocking profile: all
-        # programs are already compiled, so this decomposes the REBUILD
-        # cost (device programs vs fetch round trips vs fused probe/value
-        # checks) — the r5 chip session's 15.7s setup was opaque
+        # warm-cache setup re-run: all programs are already compiled, so
+        # its stage attribution decomposes the REBUILD cost (device
+        # programs vs fetch round trips vs fused probe/value checks)
         _stage("setup profile")
         try:
-            from amgcl_tpu.ops import stencil_device as _sdev
-            os.environ["AMGCL_TPU_PROFILE_SETUP"] = "1"
             t0 = time.perf_counter()
             s_rep = make_solver(A, prm, headline_config["solver"](),
                                 refine=headline_config["refine"])
             _PARTIAL["setup_repeat_s"] = round(time.perf_counter() - t0, 3)
-            _PARTIAL["setup_profile"] = [
-                [tag, dt] for tag, dt in _sdev.LAST_SETUP_PROFILE]
             # per-stage attribution of the warm re-run (device-setup
             # stages included), same shape as setup_attribution above
             _PARTIAL["setup_repeat_attribution"] = _setup_attr_summary(
                 s_rep.precond.setup_report())
         except Exception as e:
-            _PARTIAL["setup_profile"] = {"error": repr(e)}
-        finally:
-            os.environ.pop("AMGCL_TPU_PROFILE_SETUP", None)
+            _PARTIAL["setup_repeat_attribution"] = {"error": repr(e)}
     if _enough("bf16", 200):
         # the ROADMAP's f32-vs-bf16 hierarchy decision, measured: same
         # problem, bf16 level operators (half the HBM bytes per

@@ -156,19 +156,6 @@ def test_solve_roofline_classification():
     assert rl.solve_roofline({"flops": 0, "bytes": 0}, 10, 1.0) is None
 
 
-def test_report_carries_solve_roofline():
-    A, rhs = poisson3d(10)
-    s = make_solver(A, AMGParams(dtype=jnp.float32, coarse_enough=150),
-                    CG(maxiter=60, tol=1e-6))
-    _, r1 = s(rhs)
-    _, r2 = s(rhs)
-    rf = r2.resources["roofline"]
-    assert rf["gbps"] > 0 and rf["bound"] in ("memory", "compute")
-    assert "first_call" not in rf       # steady-state call overwrote it
-    rec = json.loads(r2.to_json())
-    assert rec["resources"]["roofline"]["gbps"] == rf["gbps"]
-
-
 def test_format_roofline_renders(amg):
     rf = amg.roofline()
     txt = rl.format_roofline(rf, rl.xla_stage_check(amg.hierarchy))

@@ -160,6 +160,46 @@ def test_vcycle_named_phases_in_trace():
         assert "amgcl/level" in asm and name in asm, name
 
 
+def _dia_bundle():
+    A, rhs = poisson3d(16)
+    return make_solver(A, AMGParams(dtype=jnp.float32, coarse_enough=200),
+                       CG(maxiter=100, tol=1e-8), refine=2), rhs, \
+        "DiaMatrix"
+
+
+def _windowed_ell_bundle():
+    from amgcl_tpu.ops.unstructured import fe_like_problem
+    A, rhs = fe_like_problem(4096, 20 * 4096, seed=0)
+    return make_solver(A, AMGParams(dtype=jnp.float32, coarse_enough=200),
+                       BiCGStab(maxiter=300, tol=1e-8), refine=2), rhs, \
+        "WindowedEllMatrix"
+
+
+@pytest.mark.parametrize("build", [_dia_bundle, _windowed_ell_bundle],
+                         ids=["dia", "windowed_ell"])
+def test_solve_program_scope_names_in_compiled_hlo(build):
+    """The scope names survive into the op-name metadata of the
+    OPTIMIZED solve program make_solver dispatches — the key a device
+    trace's per-level join reads: the level stages, the preconditioner
+    and the Krylov loop."""
+    import re
+    s, rhs, fmt = build()
+    levels = s.precond.hierarchy.levels
+    assert type(levels[0].A).__name__ == fmt
+    r = jnp.asarray(rhs, s.solver_dtype)
+    txt = s._wrapped_solve_fn().lower(
+        s.A_dev, s.A_dev64, s.precond.hierarchy, r,
+        jnp.zeros_like(r)).compile().as_text()
+    names = re.findall(r'op_name="([^"]*)"', txt)
+    for key in ("amgcl/level0/", "amgcl/level1/", "amgcl/precond",
+                "amgcl/krylov/%s" % type(s.solver).__name__):
+        assert any(key in n for n in names), key
+    # a level's stage names sit under the preconditioner scope
+    assert any(re.search(r"amgcl/precond/.*amgcl/level0/"
+                         r"(pre_smooth|restrict|down_fused)", n)
+               for n in names)
+
+
 def test_jsonl_sink_roundtrip(tmp_path):
     path = str(tmp_path / "metrics.jsonl")
     sink = JsonlSink(path)
