@@ -83,6 +83,7 @@ PJIT_ROLES = {
     "dia_spmv": "spmv", "dia_spmv_dots": "spmv", "_dia_fused": "spmv",
     "dia_residual_dot": "spmv", "dia_residual_df": "spmv",
     "dense_window_spmv": "spmv", "dense_window_fused": "spmv",
+    "well_spmv": "spmv",
     "audit_precond": "precond", "apply": "precond",
     "_where": "select",
 }

@@ -215,11 +215,24 @@ class AMG:
         """The whole build, under the ``setup/hierarchy`` span; its
         ``path`` attribute says which set-up ran: ``device`` (every
         level built on the device, ops/stencil_device.py), ``hybrid``
-        (a device-built prefix, then the host loop) or ``host``."""
+        (a device-built prefix, then the host loop) or ``host``;
+        ``well_pallas`` and ``well_xla`` count the windowed-ELL operators
+        (A, P and R of every level) that take the lane-gather kernel and
+        those that run XLA's gather."""
         with span("setup/hierarchy") as sp:
             self._build_levels(A)
             sp.set(path="host" if not self._device_built
-                   else "hybrid" if self._dev_prefix else "device")
+                   else "hybrid" if self._dev_prefix else "device",
+                   **self._well_kernels())
+
+    def _well_kernels(self):
+        from amgcl_tpu.ops.unstructured import WindowedEllMatrix
+        kernels = [M.kernel_status()[0]
+                   for lv in self.hierarchy.levels
+                   for M in (lv.A, lv.P, lv.R)
+                   if isinstance(M, WindowedEllMatrix)]
+        return {"well_pallas": kernels.count("pallas"),
+                "well_xla": kernels.count("xla")}
 
     def _build_levels(self, A: CSR):
         prm = self.prm

@@ -1,12 +1,11 @@
 """Dense-window format: gather-free unstructured SpMV for the TPU.
 
-The windowed-ELL path keeps the x-window in VMEM but still needs an
-arbitrary in-kernel gather (``x[cols]``), which Mosaic's TC lowering
-cannot legalize on real hardware (r5 chip session: every windowed-ELL
-Pallas probe declined; the XLA ``jnp.take`` fallback runs at gather
-speed — ~27 ms per 2.6M-nnz SpMV on v5e, ~1/800 of HBM bandwidth, and
-the poisson3Db-class end-to-end solve landed at 18.3 s vs the
-reference's 0.171 s CUDA row).
+Windowed ELL (ops/unstructured.py) gathers x inside VMEM with a Pallas
+kernel that scans, for each (8, 128) entry vreg, the x rows its columns
+fall in, one 2-D lane gather per row (a 1-D gather from the window does
+not lower on v5e); its time grows with the rows scanned. Its XLA form,
+one ``jnp.take``, runs at gather speed (~27 ms per 2.4M-nnz SpMV on a
+v5e, ~1/800 of HBM bandwidth).
 
 This format removes the gather entirely: after an RCM reorder each
 64-row tile's nonzeros live in a narrow contiguous column window, so

@@ -393,6 +393,13 @@ def _decided(M, A: CSR, fmt: str, cands, forced: bool = False):
             # executed-reorder provenance (ISSUE 20): this decision was
             # priced on the PERMUTED pattern — record which plan
             dec["reorder"] = dict(prov)
+        if fmt == "well":
+            # which SpMV the built matrix runs, and what its kernel scans
+            kernel, why = M.kernel_status()
+            dec["kernel"] = kernel
+            if why:
+                dec["kernel_why"] = why
+            dec.update(getattr(M, "scan_stats", {}))
         M._format_decision = dec
     except Exception:
         pass
@@ -401,12 +408,14 @@ def _decided(M, A: CSR, fmt: str, cands, forced: bool = False):
 
 def _ranked_formats(cands):
     """Ledger-driven attempt order for auto selection (ISSUE 20): the
-    structured candidates, cheapest predicted SpMV bytes first.
+    structured candidates, cheapest price first (predicted SpMV bytes,
+    on TPU with the kernel scan or the gather beside them).
     Prediction-ineligible formats keep the legacy preference order at
     the tail — the per-format conversion guards remain the ground truth
     (an attempt can still decline), and ELL stays the unconditional
     terminal fallback outside this ranking. Falls back to the legacy
     order when the prediction itself failed."""
+    from amgcl_tpu.telemetry.structure import price
     default = ("dia", "dwin", "well")
     if not cands:
         return default
@@ -417,7 +426,7 @@ def _ranked_formats(cands):
         if c is None or not c.get("eligible") \
                 or not (c.get("predicted") or {}).get("bytes"):
             return (1, default.index(f))
-        return (0, c["predicted"]["bytes"])
+        return (0, price(c))
 
     return tuple(sorted(default, key=key))
 
@@ -519,12 +528,11 @@ def to_device(A: CSR, fmt: str = "auto", dtype=jnp.float32,
                     "thresholds" % (nd, fill)})
             elif f == "dwin" and not is_cplx and not A.is_block \
                     and A.shape[0] == A.shape[1] and on_tpu:
-                # gather-free dense-window blocks (ops/densewin.py): on
-                # real TPU windowed ELL has no kernel (the in-kernel gather
-                # does not lower) and its XLA take runs at gather speed
-                # (~1/800 of HBM bw, r5 measurement) — trading HBM
-                # capacity (n·win·itemsize, budget-gated) for streaming
-                # wins whenever the matrix has banded locality. SQUARE
+                # gather-free dense-window blocks (ops/densewin.py):
+                # HBM capacity (n·win·itemsize, budget-gated) traded for
+                # streaming; the price ranks it against windowed ELL,
+                # whose lane-gather kernel scans x rows per entry vreg
+                # (and whose block or 64-bit form runs XLA's gather). SQUARE
                 # operators only: auto-converting every rectangular
                 # transfer too would multiply the per-matrix budget by
                 # the hierarchy depth without an accounting seam — the
@@ -580,32 +588,12 @@ def refresh_values(M, A: CSR, dtype):
         if new.cols.shape == M.cols.shape:
             return new
         return None
-    from amgcl_tpu.ops.unstructured import WindowedEllMatrix
-    if isinstance(M, WindowedEllMatrix):
+    from amgcl_tpu.ops import unstructured
+    if isinstance(M, unstructured.WindowedEllMatrix):
         # same-pattern value scatter into the cached tile/window
         # structure — skips tile_windows (the ufunc.at window scan is
         # the expensive part of the conversion)
-        n_tiles, tile, K = M.cols_local.shape[:3]
-        rows = A.expanded_rows()
-        flat = rows * K + (np.arange(A.nnz) - A.ptr[rows])
-        if A.nnz and (flat.max() >= n_tiles * tile * K
-                      or A.row_nnz().max() > K):
-            return None
-        vdt = np.dtype(dtype) if np.dtype(dtype).kind != "c" \
-            else A.val.dtype
-        if A.is_block:
-            br, bc = A.block_size
-            vals = np.zeros((n_tiles * tile * K, br, bc), dtype=vdt)
-            vals[flat] = A.val
-            vals = vals.reshape(n_tiles, tile, K, br, bc)
-        else:
-            vals = np.zeros(n_tiles * tile * K, dtype=vdt)
-            vals[flat] = A.val
-            vals = vals.reshape(n_tiles, tile, K)
-        return WindowedEllMatrix(
-            M.window_starts, M.cols_local,
-            jnp.asarray(vals, dtype=M.vals.dtype), A.shape, M.win,
-            M.block)
+        return unstructured.refresh_values(M, A)
     return None
 
 
@@ -642,9 +630,11 @@ def residual(f, A, x):
 
     DIA and dense-window operators take a fused single-pass Pallas kernel
     on TPU — the composed spmv + subtract costs an extra HBM round-trip of
-    A x because XLA cannot fuse across the pallas_call boundary. ELL,
-    windowed ELL and Dense stay composed: their mv is pure XLA, and XLA
-    fuses the subtraction into the gather/matmul consumer already."""
+    A x because XLA cannot fuse across the pallas_call boundary. ELL and
+    Dense stay composed: their mv is pure XLA, and XLA fuses the
+    subtraction into the gather/matmul consumer. Windowed ELL composes
+    its mv (the lane-gather kernel on TPU) with the subtraction; it has
+    no fused residual kernel."""
     with _phase("residual/" + type(A).__name__):
         return _residual(f, A, x)
 

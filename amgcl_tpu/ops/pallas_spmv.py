@@ -143,7 +143,7 @@ def pallas_mode(*dtypes):
 
 
 # Double-buffered x-window DMA for the DIA kernels: OPT-IN
-# (AMGCL_TPU_DIA_DB=1), unlike the windowed-ELL default — the serial DIA
+# (AMGCL_TPU_DIA_DB=1) — the serial DIA
 # kernel has a real-chip measurement behind it (round 2: 6x vs XLA) and
 # keeps its EXACT original geometry (1-D scratch, ref slices); the
 # prefetch variant must prove itself in a chip-session A/B before
@@ -197,8 +197,7 @@ def _resolve_tile(offsets, tile, itemsize, ndiag):
 
 def window_dma(pl, dma, i, n_tiles, nbuf):
     """Shared slot machinery for per-tile window-DMA double buffering
-    (used by the DIA kernels here and the windowed-ELL kernels in
-    ops/unstructured.py — one copy of the race-prone part).
+    (used by the DIA kernels here — one copy of the race-prone part).
     ``dma(tile_idx, slot)`` builds the async-copy descriptor. Serial
     (nbuf=1): start+wait tile i. Double (nbuf=2): tile i+1's transfer is
     issued before waiting on tile i's, riding under this tile's compute

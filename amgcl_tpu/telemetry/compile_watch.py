@@ -73,6 +73,7 @@ DECLARED_ENTRY_POINTS = (
     "ops.segment_spgemm",
     "ops.stencil_galerkin",
     "ops.transfer_smooth",
+    "ops.well_spmv",
     "parallel.dist_amg_solve",
     "parallel.dist_cg",
     "parallel.dist_cg_pipelined",

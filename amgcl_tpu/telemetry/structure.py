@@ -90,6 +90,18 @@ CANDIDATE_FORMATS = ("dense", "dia", "dwin", "well", "ell")
 #: advisor gain below which a reorder is not worth reporting
 GAIN_FLOOR = 1.15
 
+#: TPU prices (``candidate_table(on_tpu=True)``): time that is not HBM
+#: streaming, turned into bytes at the v5e's HBM rate (Google Cloud,
+#: "TPU v5e": 819 GB/s) so it ranks beside the stored bytes. One scan
+#: step of the windowed-ELL kernel (ops/unstructured.py: broadcast one x
+#: row, lane gather, compare and select on one (8, 128) entry vreg; the
+#: 85,623-row FE operator in Cuthill-McKee order on a v5e, 0.93 ms for
+#: 4,032 entry vregs x 27.75 rows), and one element of XLA's gather (the
+#: take-ELL SpMV of that operator, 27.4 ms for 85,623 x 44 slots).
+TPU_HBM_BYTES_PER_S = 819e9
+WELL_SCAN_STEP_S = 0.93e-3 / (4032 * 27.75)
+XLA_GATHER_ELEM_S = 27.4e-3 / (85623 * 44)
+
 
 def _env_int(name: str, default: int) -> int:
     try:
@@ -196,6 +208,67 @@ def tile_windows_host(A, tile: int = _TILE):
     win = int(span.max()) if n_tiles else 1
     win = -(-win // _WIN_ALIGN) * _WIN_ALIGN
     return n_tiles, rows, tiles, starts, win
+
+
+# ---------------------------------------------------------------------------
+# entry vregs of the windowed-ELL kernel (layout shared with the packer)
+# ---------------------------------------------------------------------------
+
+def vreg_slots(A) -> Tuple[int, np.ndarray]:
+    """(kv, flat): the entry-vreg layout of scalar windowed ELL. Each
+    group of 128 consecutive rows stores its ELL slots as ``kv`` vregs
+    of (8 slots, 128 lanes), lane = row within the group, slot k of a row
+    its k-th entry (its k-th smallest column in a sorted CSR);
+    ``flat[e]`` is nonzero e's position in the (n_vregs, 8, 128) array.
+    ``ops/unstructured.csr_to_windowed_ell`` packs with it."""
+    rows = A.expanded_rows()
+    k = int(np.diff(A.ptr).max()) if A.nnz else 0
+    kv = max(1, -(-k // SUBLANE))
+    slot = np.arange(A.nnz, dtype=np.int64) - A.ptr[rows]
+    flat = ((rows // LANE) * kv + slot // SUBLANE) * (SUBLANE * LANE) \
+        + (slot % SUBLANE) * LANE + rows % LANE
+    return kv, flat
+
+
+def vreg_scan(A, flat: np.ndarray, n_vregs: int, starts: np.ndarray,
+              tile: int = _TILE) -> Tuple[np.ndarray, np.ndarray]:
+    """(lo, hi): each entry vreg's scan bounds, the window-local x rows
+    (128 columns each) its real entries fall in, as ``[lo, hi)``; an
+    empty vreg gets (0, 0). ``starts`` are the tile windows'
+    starts (:func:`tile_windows_host`)."""
+    xrow = ((A.col - starts[A.expanded_rows() // tile]) // LANE) \
+        .astype(np.int32)
+    grid = np.full(n_vregs * SUBLANE * LANE, np.iinfo(np.int32).max,
+                   np.int32)
+    grid[flat] = xrow
+    lo = grid.reshape(n_vregs, -1).min(axis=1)
+    grid.fill(-1)
+    grid[flat] = xrow
+    hi = grid.reshape(n_vregs, -1).max(axis=1) + 1
+    lo[hi == 0] = 0
+    return lo, hi
+
+
+def well_scan(A, tile: int = _TILE) -> Dict[str, Any]:
+    """What the windowed-ELL kernel scans on ``A``: ``entry_vregs``
+    (stored (8, 128) entry vregs, empty ones included), ``steps`` (x rows
+    scanned, summed over them) and ``scan_xrows_mean`` (their ratio).
+    Cached on the matrix beside :func:`fast_facts`."""
+    cached = getattr(A, "_xray_scan", None)
+    if cached is not None and cached.get("tile") == tile:
+        return cached
+    n_tiles, _, _, starts, _ = tile_windows_host(A, tile)
+    kv, flat = vreg_slots(A)
+    n_vregs = n_tiles * (tile // LANE) * kv
+    lo, hi = vreg_scan(A, flat, n_vregs, starts, tile)
+    steps = int((hi - lo).sum())
+    out = {"tile": tile, "entry_vregs": int(n_vregs), "steps": steps,
+           "scan_xrows_mean": round(steps / max(n_vregs, 1), 4)}
+    try:
+        A._xray_scan = out
+    except AttributeError:
+        pass
+    return out
 
 
 def fast_facts(A, tile: int = _TILE, itemsize: int = 4
@@ -416,7 +489,15 @@ def candidate_table(A, itemsize: int = 4, on_tpu: bool = False,
     ``"budget"`` (its bytes fit ``budget_total`` but not what earlier
     conversions left in ``budget_remaining`` — a budget-STARVED pick)
     from ``"window"`` (the aligned span is too wide for any budget — a
-    structural decline a reorder might fix)."""
+    structural decline a reorder might fix).
+
+    Each row's ``price`` is what auto selection and the advisor rank by:
+    its predicted bytes, and with ``on_tpu`` also the time the format
+    spends beyond streaming them, as bytes at HBM rate — the windowed-
+    ELL kernel's scan (``entry_vregs`` x ``scan_xrows_mean`` steps) for
+    a scalar operator of <= 32-bit values, XLA's gather per stored slot
+    for ELL and for windowed ELL the kernel does not take. Off TPU the
+    price is the bytes."""
     n, m = A.shape
     nnz = max(A.nnz, 1)
     br, bc = getattr(A, "block_size", (1, 1))
@@ -429,13 +510,15 @@ def candidate_table(A, itemsize: int = 4, on_tpu: bool = False,
     facts = fast_facts(A, tile=tile, itemsize=itemsize)
     rows: List[Dict[str, Any]] = []
 
-    def cand(fmt, eligible, why, flops, stored):
+    def cand(fmt, eligible, why, flops, stored, extra_s=0.0, **info):
         rows.append({
             "format": fmt, "eligible": bool(eligible),
             **({"why": why} if why else {}),
             "predicted": {"flops": int(flops),
                           "bytes": int(stored + vec)},
-            "stored_bytes": int(stored)})
+            "stored_bytes": int(stored),
+            "price": int(stored + vec + extra_s * TPU_HBM_BYTES_PER_S),
+            **info})
 
     # dense (MXU matmul; small coarse levels)
     dense_ok = (not is_block and max(n, m) <= dense_cutoff
@@ -490,36 +573,56 @@ def candidate_table(A, itemsize: int = 4, on_tpu: bool = False,
          2 * facts["dwin_tiles"] * _DWIN_TILE * facts["dwin_win"],
          need)
 
-    # well (windowed ELL: per-tile VMEM windows + on-chip gather)
+    # well (windowed ELL: per-tile x windows, (8, 128) entry vregs; on
+    # TPU the lane-gather kernel for scalar <= 32-bit values, else XLA's
+    # gather)
     k_pad = max(4, facts["k_padded"])
+    k_well = max(SUBLANE, -(-facts["k"] // SUBLANE) * SUBLANE)
     win = facts["win"]
     well_ok = win * bc * 4 <= well_max_win_bytes
     n_tiles = facts["tiles"]
-    well_stored = (n_tiles * 4
-                   + n_tiles * tile * k_pad * (4 + itemsize * br * bc))
+    slots = n_tiles * tile * k_well
+    well_stored = n_tiles * 4 + slots * (4 + itemsize * br * bc)
+    info: Dict[str, Any] = {}
+    extra_s = 0.0
+    if on_tpu:
+        if is_block or itemsize > 4 or tile % LANE:
+            info["kernel"] = "xla"
+            extra_s = slots * XLA_GATHER_ELEM_S
+        else:
+            scan = well_scan(A, tile)
+            info = {"kernel": "pallas",
+                    "entry_vregs": scan["entry_vregs"],
+                    "scan_xrows_mean": scan["scan_xrows_mean"]}
+            extra_s = scan["steps"] * WELL_SCAN_STEP_S
     cand("well", well_ok,
          None if well_ok else
          "window %d col x 4 B > %d B VMEM budget"
          % (win * bc, well_max_win_bytes),
-         2 * n_tiles * tile * k_pad * br * bc, well_stored)
+         2 * slots * br * bc, well_stored, extra_s, **info)
 
     # ell (global gather — the unconditional fallback)
     k_ell = max(_ELL_PAD, k_pad)
     cand("ell", True, None,
          2 * n * k_ell * br * bc,
-         n * k_ell * (4 + itemsize * br * bc))
+         n * k_ell * (4 + itemsize * br * bc),
+         n * k_ell * XLA_GATHER_ELEM_S if on_tpu else 0.0)
     return rows
+
+
+def price(c: Dict[str, Any]) -> int:
+    """A candidate row's price (:func:`candidate_table`); rows recorded
+    before prices existed rank by their predicted bytes."""
+    return c.get("price", c["predicted"]["bytes"])
 
 
 def best_candidate(candidates: List[Dict[str, Any]],
                    eligible_only: bool = True
                    ) -> Optional[Dict[str, Any]]:
-    """Predicted-byte argmin over the table (eligible rows only by
-    default)."""
+    """Price argmin over the table (eligible rows only by default)."""
     rows = [c for c in candidates if c["eligible"]] if eligible_only \
         else list(candidates)
-    return min(rows, key=lambda c: c["predicted"]["bytes"]) if rows \
-        else None
+    return min(rows, key=price) if rows else None
 
 
 def decision_record(candidates: List[Dict[str, Any]], winner_fmt: str,
@@ -528,8 +631,8 @@ def decision_record(candidates: List[Dict[str, Any]], winner_fmt: str,
                     ) -> Dict[str, Any]:
     """The format-decision ledger entry ``to_device`` attaches to the
     converted matrix: the candidate table, the winner, the margin
-    (best other candidate's predicted bytes / winner's — > 1 means the
-    winner also predicted cheapest), and the ``reason``:
+    (best other candidate's price / winner's — > 1 means the winner
+    also priced cheapest), and the ``reason``:
 
     * ``"forced"`` — the caller named the format;
     * ``"budget"`` — a candidate the auto policy PREFERS to the winner
@@ -548,20 +651,19 @@ def decision_record(candidates: List[Dict[str, Any]], winner_fmt: str,
     if not forced and win is not None:
         order = {f: i for i, f in enumerate(CANDIDATE_FORMATS)}
         wi = order.get(winner_fmt, len(CANDIDATE_FORMATS))
-        wb = win["predicted"]["bytes"]
+        wb = price(win)
         for c in candidates:
             if c is win or c.get("why") != "budget":
                 continue
-            if order.get(c["format"], 99) < wi \
-                    or c["predicted"]["bytes"] < wb:
+            if order.get(c["format"], 99) < wi or price(c) < wb:
                 reason = "budget"
                 break
     margin = None
     if win is not None:
-        others = [c["predicted"]["bytes"] for c in candidates
+        others = [price(c) for c in candidates
                   if c is not win and c["eligible"]]
-        if others and win["predicted"]["bytes"]:
-            margin = round(min(others) / win["predicted"]["bytes"], 4)
+        if others and price(win):
+            margin = round(min(others) / price(win), 4)
     out: Dict[str, Any] = {"fmt": winner_fmt, "reason": reason,
                            "candidates": candidates, "margin": margin}
     if win is not None:
@@ -632,9 +734,9 @@ def advise(A, metrics: Optional[Dict[str, Any]] = None,
     """The reorder-gain advisor for ONE operator: for each permutation
     variant, re-evaluate the structural metrics and the candidate cost
     table under the permutation — host-side, predict-only — and report
-    the predicted densification and SpMV-byte gain vs the identity
-    ordering. ``gain`` is best-eligible predicted bytes (identity) /
-    best-eligible predicted bytes (permuted): the factor the format
+    the predicted densification and SpMV gain vs the identity ordering.
+    ``gain`` is the best eligible price (identity) / the best eligible
+    price (permuted, :func:`candidate_table`): the factor the format
     layer is predicted to win back if ``to_device`` saw the reordered
     operator (``cli --reorder`` / ``utils.adapters.Reordered``)."""
     met_id = metrics if metrics is not None else structure_metrics(
@@ -645,7 +747,8 @@ def advise(A, metrics: Optional[Dict[str, Any]] = None,
     out: Dict[str, Any] = {
         "identity": {"best": best_id["format"] if best_id else None,
                      "bytes": best_id["predicted"]["bytes"]
-                     if best_id else None},
+                     if best_id else None,
+                     "price": price(best_id) if best_id else None},
         "variants": []}
     if A.nnz == 0 or A.nrows == 0:
         return out
@@ -667,23 +770,21 @@ def advise(A, metrics: Optional[Dict[str, Any]] = None,
                                  dense_cutoff=dense_cutoff, tile=tile)
         best_p = best_candidate(cand_p)
         gain = None
-        if best_id and best_p and best_p["predicted"]["bytes"]:
-            gain = round(best_id["predicted"]["bytes"]
-                         / best_p["predicted"]["bytes"], 4)
-        # mechanism-matched gains: predicted bytes of each format under
+        if best_id and best_p and price(best_p):
+            gain = round(price(best_id) / price(best_p), 4)
+        # mechanism-matched gains: the price of each format under
         # identity / under the permutation, eligibility ignored — the
         # number ``bench --xray`` validates measured (same format both
-        # sides, so time tracks bytes on any platform)
-        by_id = {c["format"]: c["predicted"]["bytes"] for c in cand_id}
+        # sides, so time tracks the price on any platform)
+        by_id = {c["format"]: price(c) for c in cand_id}
         per_format = {
-            c["format"]: round(by_id[c["format"]]
-                               / c["predicted"]["bytes"], 4)
-            for c in cand_p
-            if c["predicted"]["bytes"] and by_id.get(c["format"])}
+            c["format"]: round(by_id[c["format"]] / price(c), 4)
+            for c in cand_p if price(c) and by_id.get(c["format"])}
         row = {
             "variant": name,
             "best": best_p["format"] if best_p else None,
             "bytes": best_p["predicted"]["bytes"] if best_p else None,
+            "price": price(best_p) if best_p else None,
             "gain": gain,
             "per_format": per_format,
             "densify": {
@@ -725,12 +826,13 @@ def advise(A, metrics: Optional[Dict[str, Any]] = None,
 #: sparsity PATTERN only, so PR-9 ``rebuild()`` (same pattern, new
 #: values) and farm re-registrations of the same system reuse the plan
 #: for free instead of re-running scipy's RCM
-_PERM_CACHE: Dict[Tuple[str, str], Optional[Dict[str, Any]]] = {}
+_PERM_CACHE: Dict[Tuple[str, str, bool, int],
+                  Optional[Dict[str, Any]]] = {}
 
 
 def reorder_mode() -> str:
     """``AMGCL_TPU_REORDER``, normalized: ``auto`` (default — engage
-    when the advisor predicts at least :data:`GAIN_FLOOR` byte gain),
+    when the advisor predicts at least :data:`GAIN_FLOOR` gain in price),
     ``rcm``/``cm`` (force that variant regardless of predicted gain),
     or ``off``. Read per call so flight replay's env re-application and
     per-test monkeypatching see the live value."""
@@ -758,7 +860,7 @@ def reorder_plan(A, on_tpu: bool = False, mode: Optional[str] = None,
       re-permutes values without touching scipy again,
     * ``variant`` (``rcm``/``cm``), ``fingerprint`` (identity-pattern
       digest the plan is cached under), ``predicted_gain`` (advisor
-      byte ratio, ``None`` when forced), ``n``, and the ORIGINAL
+      price ratio, ``None`` when forced), ``n``, and the ORIGINAL
       pattern refs ``ptr``/``col`` (so rebuild can recognize a caller
       handing back an original-order CSR).
 
@@ -775,7 +877,8 @@ def reorder_plan(A, on_tpu: bool = False, mode: Optional[str] = None,
     if A.nnz > max_advise_nnz():
         return None
     fp = fingerprint(A)
-    key = (fp, md)
+    # the auto decision reads the prices, which differ on and off TPU
+    key = (fp, md, bool(on_tpu), int(itemsize))
     if key in _PERM_CACHE:
         return _PERM_CACHE[key]
     plan: Optional[Dict[str, Any]] = None

@@ -8,8 +8,10 @@ Interpret-mode tests cannot catch those. Geometries:
 
 * Poisson 128^3 level 0: 7 diagonals, 2,097,152 rows, float32;
 * its level 1 in bf16: 33 diagonals on the 64^3 grid;
-* the 85,623-row FE operator in make_solver's own (identity) order:
-  row tiles of 1024 rows with an 86,016-column window.
+* the 85,623-row FE operator: in identity order, row tiles of 1024 rows
+  with an 86,016-column window; in the RCM order the executed reorder
+  gives it on TPU, a 13,312-column window and 84 tiles of 48 entry vregs
+  (8 slots x 128 rows each) for the windowed-ELL lane-gather kernel.
 
 The topology is described inside a fixture, never at import, and the
 persistent compilation cache is off around these compiles (an entry
@@ -27,6 +29,7 @@ from amgcl_tpu.ops import densewin as dw
 from amgcl_tpu.ops import fused_vec as fv
 from amgcl_tpu.ops import pallas_spmv as ps
 from amgcl_tpu.ops import pallas_vcycle as pv
+from amgcl_tpu.ops import unstructured as us
 
 N = 128
 ROWS = N ** 3
@@ -37,8 +40,10 @@ STENCIL7 = [(0, 0, 0), (0, 0, 1), (0, 0, -1), (0, 1, 0), (0, -1, 0),
 STENCIL33 = [(z, y, x) for z in (-1, 0, 1) for y in (-1, 0, 1)
              for x in (-1, 0, 1)] + [(2, 0, 0), (-2, 0, 0), (0, 2, 0),
                                      (0, -2, 0), (0, 0, 2), (0, 0, -2)]
-#: the FE operator's windowed geometry (tile_windows at tile 1024)
+#: the FE operator's windowed geometry (tile_windows at tile 1024), in
+#: identity and in RCM order
 FE_WIN = 86016
+FE_ROWS, FE_RCM_WIN, FE_KV = 85623, 13312, 6
 
 
 def offsets(stencil, g):
@@ -146,11 +151,26 @@ def test_dense_window_fe_coarse(chip):
                    x, x, x)
 
 
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_well_lane_gather_fe_level0(chip, dtype):
+    # the windowed-ELL lane-gather kernel at the FE operator's RCM
+    # geometry: 84 tiles x 48 entry vregs, each tile's x window DMA'd
+    tiles = -(-FE_ROWS // 1024)
+    vregs = tiles * 8 * FE_KV
+    compile_kernel(functools.partial(us.well_spmv, n_out=FE_ROWS,
+                                     win=FE_RCM_WIN, kv=FE_KV),
+                   chip((2 * vregs,), jnp.int32), chip((tiles,), jnp.int32),
+                   chip((vregs, 8, 128), jnp.int32),
+                   chip((vregs, 8, 128), dtype), chip((FE_ROWS,)))
+
+
 def test_window_gather_refused(chip):
-    """A 1-D gather from a VMEM x-window, the step a windowed-ELL SpMV
-    kernel needs, does not lower on v5e at the FE operator's window: that
-    is why windowed ELL has no kernel (ops/unstructured.py). If a later
-    JAX lowers it, this test fails and such a kernel becomes possible."""
+    """A 1-D gather from a VMEM x-window does not lower on v5e at the FE
+    operator's window ("Only 2D gather is supported"). The windowed-ELL
+    kernel (ops/unstructured.well_spmv) takes the 2-D route instead: a
+    lane gather within one (8, 128) vreg per x row. If a later JAX lowers
+    the 1-D gather, this test fails and that simpler kernel becomes
+    possible."""
     from jax.experimental import pallas as pl
 
     def kernel(x_ref, c_ref, o_ref):

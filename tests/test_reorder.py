@@ -246,3 +246,50 @@ def test_replay_parity_reordered(monkeypatch, tmp_path):
     assert rows["iters"]["status"] == "ok"
     assert rows["resid"]["status"] == "ok"
     flight._reset_for_tests()
+
+
+# -- the TPU price of windowed ELL: the kernel's scan ------------------------
+
+def _fe(n=2048, seed=31):
+    from amgcl_tpu.ops.unstructured import fe_like_problem
+    return fe_like_problem(n=n, nnz_target=n * 25, seed=seed)[0]
+
+
+def test_reorder_plan_on_tpu_prices_the_scan():
+    """On TPU the advisor prices windowed ELL by its lane-gather scan:
+    identity order scans many more x rows per entry vreg than the
+    Cuthill-McKee order, so the plan executes the reorder. Off TPU the
+    price is bytes alone, identity and reordered cost the same, and no
+    reorder runs."""
+    A = _fe()
+    adv = st.advise(A, on_tpu=True)
+    assert adv["identity"]["best"] == "well"
+    assert adv["best"]["format"] == "well"
+    assert adv["best"]["gain"] >= st.GAIN_FLOOR
+    plan = st.reorder_plan(A, on_tpu=True, mode="auto")
+    assert plan is not None and plan["variant"] in ("rcm", "cm")
+    assert plan["predicted_gain"] == adv["best"]["gain"]
+    # the plan cache keys on the platform: the CPU decision is its own
+    assert st.reorder_plan(A, on_tpu=False, mode="auto") is None
+    assert "best" not in st.advise(A, on_tpu=False)
+
+
+def test_setup_span_counts_well_kernels(monkeypatch):
+    """setup/hierarchy carries how many windowed-ELL operators take the
+    lane-gather kernel (well_pallas) and how many XLA's gather
+    (well_xla)."""
+    from amgcl_tpu.telemetry import tracing
+    from amgcl_tpu.ops.unstructured import WindowedEllMatrix
+    monkeypatch.setenv("AMGCL_TPU_REORDER", "rcm")
+    A = _fe(n=4096, seed=32)
+    counts = {}
+    for hook in ("0", "1"):
+        monkeypatch.setenv("AMGCL_TPU_PALLAS_INTERPRET", hook)
+        amg = AMG(A, AMGParams(coarse_enough=500))
+        attrs = tracing.RECORDER.spans("setup/hierarchy")[-1][5]
+        counts[hook] = (attrs["well_pallas"], attrs["well_xla"])
+        n_well = sum(isinstance(M, WindowedEllMatrix)
+                     for lv in amg.hierarchy.levels
+                     for M in (lv.A, lv.P, lv.R))
+        assert n_well >= 1 and sum(counts[hook]) == n_well
+    assert counts["0"][0] == 0 and counts["1"][1] == 0

@@ -233,6 +233,57 @@ def test_candidate_table_budget_reason_and_decision():
                               forced=True)["reason"] == "forced"
 
 
+def _fe_rcm(n=2048, seed=33):
+    from amgcl_tpu.ops.unstructured import fe_like_problem
+    from amgcl_tpu.utils.adapters import cuthill_mckee, permute
+    A, _ = fe_like_problem(n=n, nnz_target=n * 25, seed=seed)
+    return permute(A, cuthill_mckee(A))
+
+
+def test_candidate_table_prices_well_scan_on_tpu():
+    """With on_tpu, windowed ELL of <= 32-bit scalar values is priced by
+    its bytes plus the lane-gather scan (entry vregs x mean x rows, at
+    WELL_SCAN_STEP_S each, as bytes at HBM rate), ELL by XLA's gather;
+    off TPU every price is the predicted bytes."""
+    A = _fe_rcm()
+    scan = st.well_scan(A)
+    on = {c["format"]: c for c in st.candidate_table(A, on_tpu=True)}
+    off = {c["format"]: c for c in st.candidate_table(A, on_tpu=False)}
+    well = on["well"]
+    assert well["kernel"] == "pallas"
+    assert well["entry_vregs"] == scan["entry_vregs"]
+    assert well["scan_xrows_mean"] == scan["scan_xrows_mean"]
+    assert well["price"] == int(
+        well["predicted"]["bytes"] + scan["steps"] * st.WELL_SCAN_STEP_S
+        * st.TPU_HBM_BYTES_PER_S)
+    assert on["ell"]["price"] > on["ell"]["predicted"]["bytes"]
+    assert all(c["price"] == c["predicted"]["bytes"]
+               and "kernel" not in c for c in off.values())
+    # a 64-bit operator takes XLA's gather, priced per stored slot
+    well64 = next(c for c in st.candidate_table(A, itemsize=8, on_tpu=True)
+                  if c["format"] == "well")
+    assert well64["kernel"] == "xla" and "entry_vregs" not in well64
+
+
+def test_decision_record_carries_well_kernel(monkeypatch):
+    """A windowed-ELL decision records which SpMV the built matrix runs
+    (kernel, and kernel_why when it is XLA's), its entry vregs and the
+    mean x rows the kernel scans per vreg."""
+    import jax.numpy as jnp
+    from amgcl_tpu.ops import device as dev
+    A = _fe_rcm(n=4096)
+    M = dev.to_device(A, "auto", jnp.float32, dense_cutoff=256)
+    dec = M._format_decision
+    assert dec["fmt"] == "well"
+    assert (dec["kernel"], dec["kernel_why"]) == ("xla", "not on TPU")
+    assert dec["entry_vregs"] == M.cols_local.shape[0]
+    assert dec["scan_xrows_mean"] == st.well_scan(A)["scan_xrows_mean"]
+    monkeypatch.setenv("AMGCL_TPU_PALLAS_INTERPRET", "1")
+    dec = dev.to_device(A, "auto", jnp.float32,
+                        dense_cutoff=256)._format_decision
+    assert dec["kernel"] == "pallas" and "kernel_why" not in dec
+
+
 # ---------------------------------------------------------------------------
 # host-purity contract (STRUCTURE_CONTRACTS)
 # ---------------------------------------------------------------------------

@@ -120,8 +120,8 @@ def test_windowed_spmv_dots_seam():
 
 
 def test_windowed_seams_under_interpret_hook(monkeypatch):
-    """With the CI interpret hook on, the windowed-ELL seams still take
-    the XLA path (there is no kernel to route to) and agree with the
+    """With the CI interpret hook on, the windowed-ELL seams run the
+    lane-gather kernel (``well_spmv``, interpret mode) and agree with the
     host."""
     monkeypatch.setenv("AMGCL_TPU_PALLAS_INTERPRET", "1")
     Ap, W, x, f, w = _windowed_fixture(seed=10)
@@ -261,3 +261,100 @@ def test_amg_solve_fe_like():
     x, info = solve(rhs_p)
     r = rhs_p - Ap.spmv(np.asarray(x))
     assert np.linalg.norm(r) / np.linalg.norm(rhs_p) < 1e-6
+
+
+# -- the lane-gather kernel (well_spmv), interpret mode -----------------------
+
+def _kernel_case(case):
+    """(host CSR, value dtype) for one kernel case."""
+    import scipy.sparse as sp
+    if case in ("rcm_fe_4096", "bf16"):
+        A, _ = fe_like_problem(n=4096, nnz_target=4096 * 25, seed=21)
+        A = permute(A, cuthill_mckee(A))
+        return A, jnp.bfloat16 if case == "bf16" else jnp.float32
+    rng = np.random.RandomState(22)
+    n = 1000 if case == "rows_not_multiple_of_128" else 1536
+    band = sp.random(n, n, density=0.01, random_state=rng, format="csr")
+    M = (sp.diags(np.arange(1.0, n + 1)) + sp.triu(sp.tril(band, 300),
+                                                   -300)).tolil()
+    if case == "empty_rows":
+        for r in (0, 5, 700, 1535):
+            M.rows[r], M.data[r] = [], []
+    elif case == "max_k_row":
+        # one row reaches the maximum K (all of its 2x128-column band)
+        M[900, 640:896] = rng.rand(256)
+    M = M.tocsr()
+    M.sort_indices()
+    return CSR.from_scipy(M), jnp.float32
+
+
+@pytest.mark.parametrize("case", ["rcm_fe_4096", "rows_not_multiple_of_128",
+                                  "empty_rows", "max_k_row", "bf16"])
+def test_well_kernel_matches_host(case):
+    from amgcl_tpu.ops.unstructured import well_spmv
+    A, vdt = _kernel_case(case)
+    W = csr_to_windowed_ell(A, vdt)
+    assert W is not None
+    x = np.random.RandomState(3).rand(A.ncols).astype(np.float32)
+    # the reference multiplies the values the device holds
+    Ah = CSR(A.ptr, A.col, np.asarray(jnp.asarray(A.val, vdt), np.float64),
+             A.ncols)
+    ref = Ah.spmv(x.astype(np.float64))
+    y = np.asarray(well_spmv(W.scan, W.window_starts, W.cols_local,
+                             W.vals, jnp.asarray(x), n_out=A.nrows,
+                             win=W.win, kv=W.kv, interpret=True), np.float64)
+    np.testing.assert_allclose(y, ref, rtol=1e-5,
+                               atol=1e-5 * np.abs(ref).max())
+    if case == "empty_rows":
+        assert y[[0, 5, 700, 1535]].tolist() == [0.0] * 4
+    if case == "max_k_row":
+        k = np.diff(A.ptr)
+        assert k.argmax() == 900 and W.kv == -(-k.max() // 8)
+    # padding never widens a vreg's scan: every slot's x row (real or
+    # padding) lies in [lo, hi) of its vreg, and an empty vreg scans
+    # nothing
+    rows = np.asarray(W.cols_local).reshape(W.cols_local.shape[0], -1) >> 7
+    lo, hi = np.asarray(W.scan).reshape(-1, 2).T
+    full = hi > lo
+    assert np.all(rows[full] >= lo[full, None])
+    assert np.all(rows[full] < hi[full, None])
+    assert np.all(lo[~full] == 0) and np.all(hi[~full] == 0)
+
+
+def test_well_kernel_residual_seam(monkeypatch):
+    """The residual seam composes mv, so under the interpret hook it
+    runs the kernel; the result matches the host residual."""
+    from amgcl_tpu.ops import unstructured
+    monkeypatch.setenv("AMGCL_TPU_PALLAS_INTERPRET", "1")
+    Ap, W, x, f, _ = _windowed_fixture(seed=23)
+    assert W.kernel_status() == ("pallas", None)
+    calls = []
+    real = unstructured.well_spmv
+    monkeypatch.setattr(unstructured, "well_spmv",
+                        lambda *a, **k: calls.append(1) or real(*a, **k))
+    r = np.asarray(dev.residual(jnp.asarray(f), W, jnp.asarray(x)))
+    assert calls
+    np.testing.assert_allclose(r, f - Ap.spmv(x.astype(np.float64)),
+                               rtol=5e-4, atol=5e-4)
+
+
+def test_well_kernel_gate():
+    """Without the TPU or the interpret hook, and for block values or
+    64-bit operators, windowed ELL keeps XLA's gather and says why."""
+    Ap, W, _, _, _ = _windowed_fixture(seed=24)
+    assert W.kernel_status() == ("xla", "not on TPU")
+    W64 = csr_to_windowed_ell(Ap, jnp.float64)
+    assert W64.kernel_status()[0] == "xla"
+    _, Wb, _, _, _ = _block_fixture(n_pt=600, seed=25)
+    assert Wb.kernel_status() == ("xla", "block values")
+
+
+def test_well_scan_stats_match_structure_pricing():
+    """The packer's entry vregs and mean scan are the ones the structure
+    advisor prices (telemetry/structure.well_scan)."""
+    from amgcl_tpu.telemetry import structure as st
+    Ap, W, _, _, _ = _windowed_fixture(seed=26)
+    got = st.well_scan(Ap)
+    assert W.scan_stats == {"entry_vregs": got["entry_vregs"],
+                            "scan_xrows_mean": got["scan_xrows_mean"]}
+    assert W.cols_local.shape[0] == got["entry_vregs"]
